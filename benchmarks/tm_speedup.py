@@ -401,6 +401,8 @@ def write_json(rows, path: str = "BENCH_tm.json",
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--engines", default=",".join(DEFAULT_ENGINES))

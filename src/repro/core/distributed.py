@@ -72,7 +72,6 @@ from repro.core.api import (
 from repro.core.engines import CLAUSE_AXIS, cache_provider, get_engine
 from repro.core.types import (
     TMConfig, TMState, VoteAccumulator, clause_polarity, include_mask)
-from repro.sharding import shard_map_compat
 
 STATE_PSPEC = TMState(ta_state=P(None, CLAUSE_AXIS, None))
 
@@ -296,9 +295,9 @@ def make_sharded_prepare(cfg: TMConfig, mesh, *, engines=None,
         return {k: cache_provider(k).shard_prepare(cfg, state_l, shards)
                 for k in keys}
 
-    fn = jax.jit(shard_map_compat(local_fn, mesh=mesh,
-                                  in_specs=(STATE_PSPEC,),
-                                  out_specs=cache_specs))
+    fn = jax.jit(jax.shard_map(local_fn, mesh=mesh,
+                               in_specs=(STATE_PSPEC,),
+                               out_specs=cache_specs, check_vma=False))
 
     def prepare(state: TMState) -> TMBundle:
         state = pad_state(cfg, state, geom.n_padded)
@@ -332,9 +331,9 @@ def make_sharded_scores(cfg: TMConfig, mesh, *, engine: str = DEFAULT_ENGINE):
         part = eng.partial_scores(cfg, cache_l, x_l, pol_l)
         return jax.lax.psum(part, CLAUSE_AXIS)
 
-    fn = jax.jit(shard_map_compat(
+    fn = jax.jit(jax.shard_map(
         local_fn, mesh=mesh, in_specs=(cache_spec, P(CLAUSE_AXIS), bspec),
-        out_specs=bspec))
+        out_specs=bspec, check_vma=False))
 
     def operand(bundle: TMBundle):
         """The engine operand ``fn`` evaluates: the TA state for cache-less
@@ -364,9 +363,9 @@ def make_sharded_scores(cfg: TMConfig, mesh, *, engine: str = DEFAULT_ENGINE):
             lambda s: NamedSharding(mesh, s), spec,
             is_leaf=lambda s: isinstance(s, P))
         return jax.jit(
-            shard_map_compat(local_fn, mesh=mesh,
-                             in_specs=(cache_spec, P(CLAUSE_AXIS), bspec),
-                             out_specs=bspec),
+            jax.shard_map(local_fn, mesh=mesh,
+                          in_specs=(cache_spec, P(CLAUSE_AXIS), bspec),
+                          out_specs=bspec, check_vma=False),
             in_shardings=(as_named(cache_spec), as_named(P(CLAUSE_AXIS)),
                           as_named(bspec)),
             out_shardings=as_named(bspec),
@@ -600,11 +599,11 @@ def make_sharded_train_step(cfg: TMConfig, mesh, *, engines=None,
     mask_spec = y_spec  # batch-sharded in parallel mode, replicated otherwise
     if async_votes > 0:
         acc_spec = vote_acc_pspec(mesh)
-        sm = shard_map_compat(
+        sm = jax.shard_map(
             local_fn_async, mesh=mesh,
             in_specs=(STATE_PSPEC, cache_specs, P(CLAUSE_AXIS), acc_spec,
                       x_spec, y_spec, P(None), mask_spec),
-            out_specs=(STATE_PSPEC, cache_specs, acc_spec))
+            out_specs=(STATE_PSPEC, cache_specs, acc_spec), check_vma=False)
         donate_nums = (0, 1, 3) if resolve_donate(donate) else ()
         fn = jax.jit(sm, donate_argnums=donate_nums)
 
@@ -624,11 +623,11 @@ def make_sharded_train_step(cfg: TMConfig, mesh, *, engines=None,
                             event_overflow=bundle.event_overflow,
                             vote_acc=acc)
     else:
-        sm = shard_map_compat(
+        sm = jax.shard_map(
             local_fn, mesh=mesh,
             in_specs=(STATE_PSPEC, cache_specs, P(CLAUSE_AXIS), x_spec,
                       y_spec, P(None), mask_spec, P()),
-            out_specs=(STATE_PSPEC, cache_specs, P()))
+            out_specs=(STATE_PSPEC, cache_specs, P()), check_vma=False)
         donate_nums = (0, 1) if resolve_donate(donate) else ()
         fn = jax.jit(sm, donate_argnums=donate_nums)
 
@@ -694,8 +693,8 @@ def make_vote_refresh(cfg: TMConfig, mesh, *, parallel: bool = False,
             overflow=jnp.zeros_like(acc_l.overflow))
         return acc_out, overflow_in + total[m]
 
-    sm = shard_map_compat(local_fn, mesh=mesh, in_specs=(acc_spec, P()),
-                          out_specs=(acc_spec, P()))
+    sm = jax.shard_map(local_fn, mesh=mesh, in_specs=(acc_spec, P()),
+                       out_specs=(acc_spec, P()), check_vma=False)
     fn = jax.jit(sm, donate_argnums=(0,) if resolve_donate(donate) else ())
 
     def refresh(bundle: TMBundle) -> TMBundle:

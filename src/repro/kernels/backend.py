@@ -18,12 +18,11 @@ with
 Callers resolve ``backend='auto'|'xla'|'pallas'|'pallas_interpret'`` —
 threaded from ``TMConfig.backend`` / ``Topology.backend`` through
 ``TMSession`` — into a concrete callable via :func:`resolve`. ``auto``
-resolves to Pallas on TPU, to whatever the ``REPRO_TM_BACKEND`` environment
-override names (a hands-off hook for forcing e.g. interpret mode on a whole
-process), and to XLA otherwise, so the same config runs the fused kernels
-on hardware that has them and the reference bodies everywhere else. The CI
-gates pass explicit backends instead (``tm_serve --backend
-pallas_interpret``, the dryrun route checks, the benchmark sweep).
+resolves to compiled Pallas on TPU and to XLA everywhere else, so the same
+config runs the fused kernels on hardware that has them and the reference
+bodies elsewhere; it never resolves to the interpreter. Interpret mode is
+asked for by name only (``backend='pallas_interpret'``: the tests, ``tm_serve
+--backend pallas_interpret``, the dryrun route checks, the benchmark sweep).
 
 The partitioning contract is the *declared* form of how the sharded layer
 wires each primitive: a clause shard calls the same resolved callable on
@@ -42,7 +41,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 from typing import Callable
 
 import jax
@@ -136,24 +134,14 @@ def registered_primitives() -> tuple[str, ...]:
 def resolve_backend(backend: str = "auto") -> str:
     """``backend`` string → concrete mode (never ``'auto'``).
 
-    Resolution order for ``'auto'``: the ``REPRO_TM_BACKEND`` environment
-    override when set (forces e.g. ``pallas_interpret`` on a process that
-    cannot pass explicit backend strings), else ``'pallas'`` on TPU, else
-    ``'xla'``. Set the override before anything traces: jit caches key on
-    the config string, not the resolved mode.
+    ``'auto'`` is ``'pallas'`` on TPU and ``'xla'`` elsewhere — never
+    ``'pallas_interpret'``, which only an explicit request selects.
     """
     if backend not in BACKENDS:
         raise ValueError(
             f"unknown kernel backend {backend!r}; one of {BACKENDS}")
     if backend != "auto":
         return backend
-    env = os.environ.get("REPRO_TM_BACKEND", "")
-    if env:
-        if env not in BACKENDS or env == "auto":
-            raise ValueError(
-                f"REPRO_TM_BACKEND={env!r} must be a concrete backend "
-                f"(one of {BACKENDS[1:]})")
-        return env
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 

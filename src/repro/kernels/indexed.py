@@ -23,9 +23,11 @@ Two bodies live here:
     operands; counts stay < 2²⁴ so the arithmetic is exact, and the result
     is bit-identical to the integer form).
   * :func:`indexed_votes` — the fused Pallas body: a clause tile's
-    membership block meets the batch tile's false-literal block on-chip,
-    the falsified bitmask never leaves VMEM, and votes accumulate over the
-    clause-tile grid axis (same tiling idiom as ``kernels/clause_eval.py``).
+    membership block meets the batch tile's false-literal block on the MXU,
+    one literal tile at a time; the hit counts accumulate in VMEM scratch,
+    the falsified bitmask never leaves VMEM, and votes accumulate into a
+    lane-dense class block over the clause-tile grid axis (same tiling
+    idiom as ``kernels/clause_eval.py``).
 
 Maintenance is the third body: :func:`index_update_batched` replays a
 fixed-shape masked event buffer in O(events) *vectorised* work instead of
@@ -52,24 +54,20 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.clause_eval import (
+    BATCH_TILE, CLAUSE_TILE, LANE, accumulate_class_votes, pad_to)
 
 # Mirrors core.indexing.NA — kernels/ stays free of core/ imports; the
 # sentinel is part of the ClauseIndex layout contract (tests pin equality).
 NA = jnp.int32(-1)
 
-BATCH_TILE = 8       # sublane-friendly batch tile
-CLAUSE_TILE = 128    # clauses per grid step
-LANE = 128           # lane width; literal dim padded to a multiple
-
-
-def _pad_to(x: jax.Array, axis: int, mult: int, value=0) -> jax.Array:
-    size = x.shape[axis]
-    pad = (-size) % mult
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths, constant_values=value)
+# Literal columns per grid step. The (1, CLAUSE_TILE, LIT_TILE) int32 pos
+# block is 1 MB (2 MB double-buffered) plus a 1 MB f32 membership temporary,
+# whatever 2o is: wider literal axes (IMDb-like 2o = 10k-40k) take more
+# grid steps, never more VMEM. 2o <= LIT_TILE runs as one full-width block.
+LIT_TILE = 2048
 
 
 # ---------------------------------------------------------------------------
@@ -100,29 +98,38 @@ def indexed_votes_xla(pos: jax.Array, lit: jax.Array,
                        pol.astype(jnp.int32))
 
 
-def _indexed_votes_kernel(pos_ref, lit_ref, pol_ref, o_ref):
-    """Grid (B_tiles, m, n_tiles); j = clause-tile index iterates fastest.
+def _indexed_votes_kernel(pos_ref, fl_ref, pol_ref, o_ref, hits_ref):
+    """Grid (B_tiles, m, n_tiles, l_tiles); the literal tile l is fastest.
 
-    pos_ref: (1, CLAUSE_TILE, L)   int32 — position block (NA = excluded)
-    lit_ref: (BATCH_TILE, L)       int32 — literal truth values
-    pol_ref: (1, CLAUSE_TILE)      int32 — ±1 clause polarity (0 = padding)
-    o_ref:   (BATCH_TILE, 1)       int32 — votes, accumulated over j
+    pos_ref:  (1, CLAUSE_TILE, Lt)    int32 — position block (NA = excluded)
+    fl_ref:   (BATCH_TILE, Lt)        f32   — false-literal indicators
+    pol_ref:  (1, CLAUSE_TILE)        int32 — ±1 clause polarity (0 = padding)
+    o_ref:    (BATCH_TILE, m_pad)     int32 — votes, resident over (i, j, l)
+    hits_ref: (BATCH_TILE, CLAUSE_TILE) f32 scratch — false members so far
     """
-    j = pl.program_id(2)
+    i, j, l = pl.program_id(1), pl.program_id(2), pl.program_id(3)
 
-    @pl.when(j == 0)
-    def _init():
+    @pl.when((i == 0) & (j == 0) & (l == 0))
+    def _init_votes():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    member = pos_ref[0] != -1                           # (Ct, L); -1 == NA
-    false_lit = lit_ref[...] == 0                       # (Bt, L)
-    # the falsified bitmask lives entirely on-chip: a clause is falsified
-    # iff any of its member literals is false in the sample
-    hit = member[None, :, :] & false_lit[:, None, :]    # (Bt, Ct, L)
-    falsified = jnp.any(hit, axis=-1)                   # (Bt, Ct)
-    sign = pol_ref[0][None, :]                          # (1, Ct)
-    votes = jnp.sum(jnp.where(falsified, -sign, 0), axis=1, dtype=jnp.int32)
-    o_ref[...] += votes[:, None]
+    @pl.when(l == 0)
+    def _init_hits():
+        hits_ref[...] = jnp.zeros_like(hits_ref)
+
+    # one MXU contraction per literal tile: 0/1 operands, f32 accumulation,
+    # counts ≤ 2o < 2²⁴ — exact, so `hits > 0` matches the XLA body
+    member = (pos_ref[0] != -1).astype(jnp.float32)     # (Ct, Lt); -1 == NA
+    hits_ref[...] += jax.lax.dot_general(
+        fl_ref[...], member, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)             # (Bt, Ct)
+
+    @pl.when(l == pl.num_programs(3) - 1)
+    def _vote():
+        falsified = hits_ref[...] > 0
+        sign = pol_ref[...]                             # (1, Ct)
+        votes = jnp.sum(jnp.where(falsified, -sign, 0), axis=1, keepdims=True)
+        accumulate_class_votes(o_ref, i, votes)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -138,36 +145,35 @@ def indexed_votes(
     Same contract as :func:`indexed_votes_xla`; ``pol`` is the ±1 sign per
     clause *row of this tensor* — the global polarity single-device, the
     shard's local slice under shard_map (partial sums completed by the one
-    vote psum). Padding invariants: clause rows beyond n are padded with
-    ``NA`` positions (member-of-nothing → never falsified) and sign 0;
-    literal columns beyond 2o are padded ``NA`` in ``pos`` so the literal
-    pad value never matters.
+    vote psum). ``pos`` is read in place, never padded: a partial trailing
+    clause tile meets sign-0 polarity, and a partial trailing literal tile
+    meets false-literal columns padded with 0 (not false), so whatever the
+    out-of-range block entries hold contributes nothing.
     """
     m, n, L = pos.shape
     b = lit.shape[0]
+    ct, lt = min(n, CLAUSE_TILE), min(L, LIT_TILE)
 
-    posp = _pad_to(_pad_to(pos.astype(jnp.int32), 2, LANE, value=-1),
-                   1, CLAUSE_TILE, value=-1)
-    litp = _pad_to(_pad_to(lit.astype(jnp.int32), 1, LANE, value=1),
-                   0, BATCH_TILE, value=1)
-    polp = _pad_to(pol.astype(jnp.int32)[None, :], 1, CLAUSE_TILE)
-    n_pad, l_pad = posp.shape[1], posp.shape[2]
-    b_pad = litp.shape[0]
+    fl = pad_to(pad_to((lit == 0).astype(jnp.float32), 1, lt), 0, BATCH_TILE)
+    polp = pad_to(pol.astype(jnp.int32)[None, :], 1, ct)
+    b_pad, m_pad = fl.shape[0], pl.cdiv(m, LANE) * LANE
 
-    grid = (b_pad // BATCH_TILE, m, n_pad // CLAUSE_TILE)
+    grid = (b_pad // BATCH_TILE, m, pl.cdiv(n, ct), pl.cdiv(L, lt))
     out = pl.pallas_call(
         _indexed_votes_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, CLAUSE_TILE, l_pad), lambda bb, i, j: (i, j, 0)),
-            pl.BlockSpec((BATCH_TILE, l_pad), lambda bb, i, j: (bb, 0)),
-            pl.BlockSpec((1, CLAUSE_TILE), lambda bb, i, j: (0, j)),
+            pl.BlockSpec((1, ct, lt), lambda bb, i, j, l: (i, j, l)),
+            pl.BlockSpec((BATCH_TILE, lt), lambda bb, i, j, l: (bb, l)),
+            pl.BlockSpec((1, ct), lambda bb, i, j, l: (0, j)),
         ],
-        out_specs=pl.BlockSpec((BATCH_TILE, 1), lambda bb, i, j: (bb, i)),
-        out_shape=jax.ShapeDtypeStruct((b_pad, m), jnp.int32),
+        out_specs=pl.BlockSpec((BATCH_TILE, m_pad),
+                               lambda bb, i, j, l: (bb, 0)),
+        out_shape=jax.ShapeDtypeStruct((b_pad, m_pad), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((BATCH_TILE, ct), jnp.float32)],
         interpret=interpret,
-    )(posp, litp, polp)
-    return out[:b]
+    )(pos.astype(jnp.int32), fl, polp)
+    return out[:b, :m]
 
 
 # ---------------------------------------------------------------------------

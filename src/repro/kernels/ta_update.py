@@ -7,8 +7,13 @@ broadcast operands — a pure VPU kernel; tiling keeps the uniforms and TA
 block resident in VMEM so the update is one HBM read + one write of the
 TA state per step.
 
-Layout: clauses on sublanes (CLAUSE_TILE), literals on lanes (LIT_TILE,
-multiple of 128).
+Layout: clauses on sublanes (CLAUSE_TILE), literals on lanes (the whole
+literal axis when 2o ≤ LIT_TILE, else LIT_TILE-wide tiles). The three
+per-clause gates travel as one int32 feedback code per clause and the
+literals as int32, because Mosaic has no int8 vector comparison on the
+TPU; the TA state is read and written as int16 and updated in int32.
+Trailing tiles may be partial: their out-of-range entries only reach
+out-of-range outputs, whose writes are dropped — no operand is padded.
 """
 from __future__ import annotations
 
@@ -18,26 +23,17 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-CLAUSE_TILE = 8
-LIT_TILE = 128
+CLAUSE_TILE = 32     # int16 state tiles are (16, 128): a multiple of it
+LIT_TILE = 2048      # max literal columns per block (multiple of 128)
 
-
-def _pad_to(x, axis, mult, value=0):
-    size = x.shape[axis]
-    pad = (-size) % mult
-    if pad == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, pad)
-    return jnp.pad(x, widths, constant_values=value)
+# feedback-code bits, one int32 per clause
+_CLAUSE_TRUE, _TYPE_I, _ACTIVE = 1, 2, 4
 
 
 def _update_kernel(
     ta_ref,        # (Ct, Lt) int16
-    lit_ref,       # (1, Lt) int8   — literal truth values
-    cout_ref,      # (Ct, 1) int8   — clause outputs (learning semantics)
-    type_i_ref,    # (Ct, 1) int8   — 1: Type I, 0: Type II (inactive → gate)
-    active_ref,    # (Ct, 1) int8   — clause update gate (bernoulli(p))
+    lit_ref,       # (1, Lt) int32   — literal truth values
+    code_ref,      # (Ct, 1) int32   — feedback code bits (see _CLAUSE_TRUE…)
     u_ref,         # (Ct, Lt) float32 — uniforms for Type I branches
     o_ref,         # (Ct, Lt) int16
     *,
@@ -45,24 +41,24 @@ def _update_kernel(
     s: float,
     boost_true_positive: bool,
 ):
-    ta = ta_ref[...]
-    lit = lit_ref[0][None, :]                     # (1, Lt)
-    c1 = cout_ref[...] == 1                       # (Ct, 1)
-    is_t1 = type_i_ref[...] == 1
-    active = active_ref[...] == 1
+    ta = ta_ref[...].astype(jnp.int32)
+    l1 = lit_ref[...] == 1                        # (1, Lt)
+    code = code_ref[...]                          # (Ct, 1)
+    c1 = (code & _CLAUSE_TRUE) != 0
+    is_t1 = (code & _TYPE_I) != 0
+    active = (code & _ACTIVE) != 0
     u = u_ref[...]
     include = ta > n_states
 
     inv_s = 1.0 / s
     p_reward = 1.0 if boost_true_positive else 1.0 - inv_s
-    l1 = lit == 1
 
     # Type I deltas
     reward = c1 & l1 & (u < p_reward)
     penalty = ((c1 & ~l1) | ~c1) & (u < inv_s)
-    d1 = reward.astype(jnp.int16) - penalty.astype(jnp.int16)
+    d1 = reward.astype(jnp.int32) - penalty.astype(jnp.int32)
     # Type II deltas
-    d2 = (c1 & ~l1 & ~include).astype(jnp.int16)
+    d2 = (c1 & ~l1 & ~include).astype(jnp.int32)
 
     delta = jnp.where(active & is_t1, d1, jnp.where(active & ~is_t1, d2, 0))
     o_ref[...] = jnp.clip(ta + delta, 1, 2 * n_states).astype(jnp.int16)
@@ -86,17 +82,13 @@ def ta_update(
 ) -> jax.Array:
     """Apply one class-round of feedback. Returns updated (n, 2o) int16."""
     n, L = ta_row.shape
-    ta = _pad_to(_pad_to(ta_row, 1, LIT_TILE), 0, CLAUSE_TILE)
-    n_pad, l_pad = ta.shape
-    litp = _pad_to(lit.astype(jnp.int8)[None, :], 1, LIT_TILE)
-    cout = _pad_to(clause_out.astype(jnp.int8)[:, None], 0, CLAUSE_TILE)
-    t1 = _pad_to(gets_type_i.astype(jnp.int8)[:, None], 0, CLAUSE_TILE)
-    act = _pad_to(active.astype(jnp.int8)[:, None], 0, CLAUSE_TILE)
-    # uniform padding value 1.0 ⇒ no spurious transitions in padded region
-    u = _pad_to(_pad_to(uniforms, 1, LIT_TILE, 1.0), 0, CLAUSE_TILE, 1.0)
+    ct, lt = min(n, CLAUSE_TILE), min(L, LIT_TILE)
+    code = ((clause_out == 1).astype(jnp.int32) * _CLAUSE_TRUE
+            + gets_type_i.astype(bool).astype(jnp.int32) * _TYPE_I
+            + active.astype(bool).astype(jnp.int32) * _ACTIVE)
 
-    grid = (n_pad // CLAUSE_TILE, l_pad // LIT_TILE)
-    out = pl.pallas_call(
+    grid = (pl.cdiv(n, ct), pl.cdiv(L, lt))
+    return pl.pallas_call(
         functools.partial(
             _update_kernel,
             n_states=n_states,
@@ -105,15 +97,13 @@ def ta_update(
         ),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((CLAUSE_TILE, LIT_TILE), lambda i, j: (i, j)),
-            pl.BlockSpec((1, LIT_TILE), lambda i, j: (0, j)),
-            pl.BlockSpec((CLAUSE_TILE, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((CLAUSE_TILE, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((CLAUSE_TILE, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((CLAUSE_TILE, LIT_TILE), lambda i, j: (i, j)),
+            pl.BlockSpec((ct, lt), lambda i, j: (i, j)),
+            pl.BlockSpec((1, lt), lambda i, j: (0, j)),
+            pl.BlockSpec((ct, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((ct, lt), lambda i, j: (i, j)),
         ],
-        out_specs=pl.BlockSpec((CLAUSE_TILE, LIT_TILE), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, l_pad), jnp.int16),
+        out_specs=pl.BlockSpec((ct, lt), lambda i, j: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((n, L), jnp.int16),
         interpret=interpret,
-    )(ta, litp, cout, t1, act, u)
-    return out[:n, :L]
+    )(ta_row.astype(jnp.int16), lit.astype(jnp.int32)[None, :],
+      code[:, None], uniforms)

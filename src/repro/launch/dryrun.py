@@ -15,7 +15,7 @@ import jax               # noqa: E402
 from repro.configs import (  # noqa: E402
     ARCHS, SKIPPED_CELLS, get_config, get_shape, shapes_for)
 from repro.launch import hlo as hlo_mod  # noqa: E402
-from repro.launch.mesh import make_production_mesh, mesh_context  # noqa: E402
+from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.sharding import named_shardings  # noqa: E402
 from repro.steps import make_step  # noqa: E402
 
@@ -42,7 +42,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
     # donate what the next step overwrites: train → state, decode → caches;
     # serving params are shared across steps and must never be donated.
     donate = {"train": (0,), "decode": (1,), "prefill": ()}[step.meta["kind"]]
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(step.fn, in_shardings=in_sh, out_shardings=out_sh,
                          donate_argnums=donate)
         lowered = jitted.lower(*step.arg_structs)

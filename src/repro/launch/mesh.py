@@ -8,28 +8,6 @@ from __future__ import annotations
 import jax
 
 
-def mesh_context(mesh):
-    """``jax.set_mesh(mesh)`` where available; the ``Mesh`` context manager
-    on older jax (0.4.x has no ``jax.set_mesh`` — entering the mesh itself
-    sets the resource env, which is all the explicit-NamedSharding jit
-    call sites here need)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
-
-
-def _mesh_kwargs(n_axes: int) -> dict:
-    """``axis_types=`` only where the installed jax supports it.
-
-    ``jax.sharding.AxisType`` landed after 0.4.37 (the container's jax);
-    older versions treat every axis as Auto already, so omitting the kwarg
-    is semantically identical there.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single-pod (256 chips) or 2×16×16 multi-pod (512 chips).
 
@@ -49,7 +27,8 @@ def make_production_mesh(*, multi_pod: bool = False):
             "before importing jax")
     import numpy as np
     return jax.sharding.Mesh(
-        np.asarray(devices).reshape(shape), axes, **_mesh_kwargs(len(axes)))
+        np.asarray(devices).reshape(shape), axes,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -61,4 +40,4 @@ def make_host_mesh(data: int = 1, model: int = 1):
         raise RuntimeError(f"need {n} devices, have {len(devices)}")
     return jax.sharding.Mesh(
         np.asarray(devices).reshape(data, model), ("data", "model"),
-        **_mesh_kwargs(2))
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)

@@ -34,7 +34,7 @@ import jax
 from repro.configs import get_config, get_shape
 from repro.configs.base import ModelConfig, ShapeSpec
 from repro.launch import hlo as hlo_mod
-from repro.launch.mesh import mesh_context, make_production_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.sharding import named_shardings
 from repro.steps import make_step
 
@@ -96,7 +96,7 @@ def _measure(cfg, shape, mesh, *, microbatches, kind):
     step = make_step(cfg, shape_p, mesh, **kw)
     in_sh = named_shardings(mesh, step.in_specs)
     out_sh = named_shardings(mesh, step.out_specs)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         compiled = (
             jax.jit(step.fn, in_shardings=in_sh, out_shardings=out_sh)
             .lower(*step.arg_structs).compile())

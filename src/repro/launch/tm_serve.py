@@ -336,6 +336,8 @@ def resolve_flags(smoke: bool, **flags) -> dict:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description="batched TM serving benchmark")
     ap.add_argument("--engine", default=None,
                     help="comma-separated registry engine names")

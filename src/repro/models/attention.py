@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.models.common import apply_rope, dense_init, rmsnorm
-from repro.sharding import Policy, current_mesh, shard_map_compat
+from repro.sharding import Policy
 
 NEG_INF = -2.0 ** 30  # large-but-finite: keeps masked softmax NaN-free
 
@@ -284,7 +284,7 @@ def decode_attend(p, x, cache, pos, *, n_heads, n_kv_heads, head_dim,
         # dim made GSPMD reshard the whole cache every layer — measured
         # 3.97 GB bytes + 490 MB collectives per layer on qwen2-72b
         # decode_32k vs ~75 MB of cache physics (§Perf hillclimb A).
-        mesh = current_mesh()
+        mesh = jax.sharding.get_abstract_mesh()
         axis = policy.model_axis
         bb = policy.b
 
@@ -318,7 +318,7 @@ def decode_attend(p, x, cache, pos, *, n_heads, n_kv_heads, head_dim,
             out = acc_g / jnp.maximum(l_g, 1e-30)[..., None]
             return out, ck, cv, cp
 
-        out, new_k, new_v, new_p = shard_map_compat(
+        out, new_k, new_v, new_p = jax.shard_map(
             shard_fn,
             mesh=mesh,
             in_specs=(P(bb, None, None),
@@ -331,6 +331,7 @@ def decode_attend(p, x, cache, pos, *, n_heads, n_kv_heads, head_dim,
                        P(bb, None, axis, None),
                        P(bb, None, axis, None),
                        P(bb, axis)),
+            check_vma=False,
         )(q, k_new[:, 0], v_new[:, 0], cache["k"], cache["v"],
           cache["pos"], pos_b)
         cache = {"k": new_k, "v": new_v, "pos": new_p}

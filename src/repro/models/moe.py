@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.models.common import activation, dense_init
-from repro.sharding import DATA, Policy, current_mesh, shard_map_compat
+from repro.sharding import DATA, Policy
 
 
 def init_moe(rng, d_model, d_ff_expert, n_experts, *, n_shared=0,
@@ -192,7 +192,7 @@ def _moe_shard_map(p, xg, *, top_k, capacity, act, policy: Policy,
         output a partial sum);
       * ONE psum over `model` of the (G_local, T, d) combined output.
     """
-    mesh = current_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     bb = policy.b
     m_axis = policy.model_axis
     engine = {"einsum": moe_einsum, "sort": moe_sort}[dispatch]
@@ -216,7 +216,7 @@ def _moe_shard_map(p, xg, *, top_k, capacity, act, policy: Policy,
             ce = jax.lax.pmean(ce, bb)
         return out, me, ce
 
-    out, me, ce = shard_map_compat(
+    out, me, ce = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bb, None, None),                 # xg: groups on batch axes
                   P(DATA, None),                     # router (d, E)
@@ -224,6 +224,7 @@ def _moe_shard_map(p, xg, *, top_k, capacity, act, policy: Policy,
                   P(None, DATA, m_axis),             # w_up
                   P(None, m_axis, DATA)),            # w_down (E, f, d)
         out_specs=(P(bb, None, None), P(), P()),
+        check_vma=False,
     )(xg, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     return out, (me, ce)
 

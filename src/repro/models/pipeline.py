@@ -15,8 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding import shard_map_compat
-
 
 def gpipe_apply(stage_fn, stage_params, x_micro, *, mesh, axis: str):
     """Run ``n_stages = mesh[axis]`` pipeline stages over microbatches.
@@ -61,8 +59,9 @@ def gpipe_apply(stage_fn, stage_params, x_micro, *, mesh, axis: str):
         # outputs live on the last stage only (zeros elsewhere): share them
         return jax.lax.psum(out, axis)
 
-    return shard_map_compat(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
+        check_vma=False,
     )(stage_params, x_micro)

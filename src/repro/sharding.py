@@ -23,33 +23,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 POD, DATA, MODEL = "pod", "data", "model"
 
 
-def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` on new jax; ``jax.experimental.shard_map`` on 0.4.x.
-
-    Replication checking is disabled either way (``check_vma`` new /
-    ``check_rep`` old): these call sites assemble outputs whose replication
-    the checker cannot prove (masked scatters, psum-combined partials).
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm_old
-    return sm_old(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-
-
-def current_mesh():
-    """The mesh in scope: ``jax.sharding.get_abstract_mesh()`` on new jax;
-    the resource-env physical mesh (entered via ``launch.mesh.mesh_context``
-    / ``with mesh:``) on 0.4.x."""
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is not None:
-        return get_am()
-    from jax._src import mesh as mesh_lib
-    return mesh_lib.thread_resources.env.physical_mesh
-
-
 @dataclasses.dataclass(frozen=True)
 class Policy:
     """Activation/param sharding policy bound to mesh axis names."""

@@ -52,21 +52,22 @@ def random_state(cfg, seed=0, density=0.4):
 # ---------------------------------------------------------------------------
 
 
-def test_resolve_backend_auto_is_xla_off_tpu(monkeypatch):
-    monkeypatch.delenv("REPRO_TM_BACKEND", raising=False)
+def test_resolve_backend_auto_is_xla_off_tpu():
     assert jax.default_backend() != "tpu"  # this container
     assert kbackend.resolve_backend("auto") == "xla"
     assert kbackend.pallas_mode() == "pallas_interpret"
 
 
 def test_resolve_backend_env_override(monkeypatch):
+    """The backend is chosen by the platform or by name, never by the
+    environment: the old ``REPRO_TM_BACKEND`` hook is gone, and interpret
+    mode is reached only through an explicit ``backend=``."""
     monkeypatch.setenv("REPRO_TM_BACKEND", "pallas_interpret")
-    assert kbackend.resolve_backend("auto") == "pallas_interpret"
-    # explicit backends ignore the env hook
+    assert kbackend.resolve_backend("auto") == "xla"
+    assert kbackend.resolve_backend("pallas_interpret") == "pallas_interpret"
     assert kbackend.resolve_backend("xla") == "xla"
-    monkeypatch.setenv("REPRO_TM_BACKEND", "auto")
-    with pytest.raises(ValueError):
-        kbackend.resolve_backend("auto")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert kbackend.resolve_backend("auto") == "pallas"
 
 
 def test_resolve_backend_rejects_unknown():

@@ -124,3 +124,65 @@ def test_ta_update_bounds():
     out = np.asarray(ta_mod.ta_update(ta, lit, cout, t1, act, u,
                                       n_states=n_states, s=2.0))
     assert out.min() >= 1 and out.max() <= 2 * n_states
+
+
+# ---------------------------------------------------------------------------
+# TPU blockings: lane-dense vote blocks, partial clause / literal tiles
+# ---------------------------------------------------------------------------
+
+# (m, n, o, b): m and b not multiples of 8, n past one clause tile and not a
+# multiple of it; m=130 takes the vote block past one 128-lane group
+BLOCKING_SHAPES = [(10, 300, 40, 13), (3, 129, 9, 1), (130, 4, 6, 3)]
+
+
+@pytest.mark.parametrize("shape", BLOCKING_SHAPES)
+def test_lane_dense_votes_slice_unaligned_shapes(shape):
+    m, n, o, b = shape
+    include, x = make_case(m, n, o, b, seed=sum(shape))
+    lit = jnp.concatenate([x, 1 - x], axis=-1)
+    pol = jnp.where(jnp.arange(n) < n // 2, 1, -1).astype(jnp.int32)
+    inc_packed = pack_bits(include.astype(jnp.uint8))
+    got = clause_eval.clause_votes_packed(inc_packed, packed_literals(x), pol)
+    assert got.shape == (b, m)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(kref.clause_votes_ref(include,
+                                                                   lit)))
+    outs = clause_eval.clause_outputs_packed(inc_packed, packed_literals(x))
+    assert outs.shape == (b, m, n) and outs.dtype == jnp.int8
+    np.testing.assert_array_equal(
+        np.asarray(outs), np.asarray(kref.clause_outputs_ref(include, lit)))
+
+
+@pytest.mark.parametrize("m,n,o,b", [(3, 130, 1100, 11), (10, 9, 20, 13)])
+def test_indexed_votes_literal_tiles_match_xla(m, n, o, b):
+    """2o = 2200 spans two literal tiles (the second partial): the hit
+    counts accumulated over tiles give the XLA body's votes exactly."""
+    from repro.kernels import indexed
+    from repro.core.types import literals_from_input
+    include, x = make_case(m, n, o, b, seed=m * n)
+    pos = jnp.where(include, 3, -1).astype(jnp.int32)
+    lit = literals_from_input(x)
+    pol = jnp.where(jnp.arange(n) % 3 == 0, 1, -1).astype(jnp.int32)
+    got = indexed.indexed_votes(pos, lit, pol)
+    assert got.shape == (b, m)
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(indexed.indexed_votes_xla(pos, lit, pol)))
+
+
+def test_ta_update_partial_tiles_match_ref():
+    """45 clauses (two clause tiles, the second partial) × 2200 literals
+    (two literal tiles, the second partial): no padding, same result."""
+    rng = np.random.default_rng(3)
+    n, L, n_states = 45, 2200, 50
+    ta = jnp.asarray(rng.integers(1, 2 * n_states + 1, (n, L)), jnp.int16)
+    lit = jnp.asarray(rng.integers(0, 2, L), jnp.uint8)
+    cout = jnp.asarray(rng.integers(0, 2, n), jnp.uint8)
+    t1 = jnp.asarray(rng.integers(0, 2, n), bool)
+    act = jnp.asarray(rng.integers(0, 2, n), bool)
+    u = jnp.asarray(rng.uniform(size=(n, L)), jnp.float32)
+    got = ta_mod.ta_update(ta, lit, cout, t1, act, u, n_states=n_states,
+                           s=3.7, boost_true_positive=True)
+    want = kref.ta_update_ref(ta, lit, cout, t1, act, u, n_states=n_states,
+                              s=3.7, boost_true_positive=True)
+    assert got.shape == (n, L) and got.dtype == jnp.int16
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

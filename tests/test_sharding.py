@@ -23,7 +23,7 @@ SCRIPT = textwrap.dedent("""
 
     from repro.configs import get_config
     from repro.configs.base import ShapeSpec
-    from repro.launch.mesh import make_host_mesh, mesh_context
+    from repro.launch.mesh import make_host_mesh
     from repro.models.model import build
     from repro.sharding import Policy, named_shardings, param_specs
     from repro.steps import make_decode_step, make_train_step
@@ -54,7 +54,7 @@ SCRIPT = textwrap.dedent("""
     dstep = make_decode_step(cfg, dshape, mesh)
     in_sh = named_shardings(mesh, dstep.in_specs)
     out_sh = named_shardings(mesh, dstep.out_specs)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         fn = jax.jit(dstep.fn, in_shardings=in_sh, out_shardings=out_sh)
         c2 = jax.device_put(model.init_cache(2, 16), in_sh[1])
         p2 = jax.device_put(params, in_sh[0])
@@ -86,7 +86,7 @@ SCRIPT = textwrap.dedent("""
     new_ref, m_ref = jax.jit(tstep_ref.fn)(state, batch)
     in_sh = named_shardings(mesh, tstep.in_specs)
     out_sh = named_shardings(mesh, tstep.out_specs)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         fns = jax.jit(tstep.fn, in_shardings=in_sh, out_shardings=out_sh)
         new_sh, m_sh = fns(jax.device_put(state, in_sh[0]),
                            jax.device_put(batch, in_sh[1]))
@@ -105,7 +105,7 @@ SCRIPT = textwrap.dedent("""
     xm = jnp.asarray(rng.normal(size=(4, 8, 32)) * 0.3, jnp.float32)
     out_ref, aux_ref = moe_block(pm, xm, top_k=2, capacity_factor=1.5,
                                  policy=Policy.none())
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         pol = Policy.for_mesh(mesh)
         pm_sh = jax.device_put(pm, NamedSharding(mesh, P()))
         fn = jax.jit(lambda p, x: moe_block(
@@ -128,7 +128,7 @@ SCRIPT = textwrap.dedent("""
     ref = xpp
     for si in range(S):
         ref = jax.vmap(lambda xm: stage(Ws[si], xm))(ref)
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         outpp = jax.jit(lambda p, xx: gpipe_apply(
             stage, p, xx, mesh=mesh, axis="data"))(Ws, xpp)
     np.testing.assert_allclose(np.asarray(outpp), np.asarray(ref),
