@@ -25,7 +25,7 @@ from typing import Any, Iterable
 import jax
 import jax.numpy as jnp
 
-from repro.core import indexing, tm
+from repro.core import indexing, scopes, tm
 from repro.core.engines import cache_provider, get_engine, registered_engines
 from repro.core.types import TMConfig, TMState, include_mask, init_tm
 
@@ -152,18 +152,21 @@ def bundle_predict(
     return jnp.argmax(bundle_scores(bundle, x, engine=engine), axis=-1)
 
 
-def sync_caches(bundle: TMBundle, new_state: TMState,
-                buf: indexing.EventBuffer) -> TMBundle:
-    """New bundle whose caches absorbed the buffer's events via their
-    providers; the bundle's overflow counter accumulates the buffer's."""
-    caches = {key: cache_provider(key).update_cache(
-                  bundle.cfg, cache, new_state, buf.events)
-              for key, cache in bundle.caches.items()}
-    overflow = buf.overflow
-    if bundle.event_overflow is not None:
-        overflow = overflow + bundle.event_overflow
-    return TMBundle(cfg=bundle.cfg, state=new_state, caches=caches,
-                    event_overflow=overflow, vote_acc=bundle.vote_acc)
+def replay_events(cfg: TMConfig, caches: dict, old_inc: jax.Array,
+                  new_state: TMState, max_events: int
+                  ) -> tuple[dict, indexing.EventBuffer]:
+    """The tail every topology's step shares: diff the include masks into a
+    counted event buffer (``tm.events``), then every cache absorbs the
+    events through its provider (``tm.cache_sync``). Returns the new caches
+    and the buffer, whose ``overflow`` the caller accumulates."""
+    with jax.named_scope(scopes.EVENTS):
+        buf = indexing.events_from_transition(
+            old_inc, include_mask(cfg, new_state), max_events)
+    with jax.named_scope(scopes.CACHE_SYNC):
+        caches = {key: cache_provider(key).update_cache(
+                      cfg, cache, new_state, buf.events)
+                  for key, cache in caches.items()}
+    return caches, buf
 
 
 def train_step(
@@ -192,15 +195,25 @@ def train_step(
     per-sample randomness but apply no update, so a trailing partial batch
     can pad to the compiled shape without a recompile and without training
     on garbage (the ``TsetlinMachine.fit`` padding contract).
+
+    The phases run under the scope names of ``core/scopes.py``, as in the
+    sharded bodies: ``tm.feedback`` (the update, ``tm.draws`` inside it),
+    then ``replay_events`` (``tm.events``, ``tm.cache_sync``).
     """
     cfg = bundle.cfg
-    old_inc = include_mask(cfg, bundle.state)
+    with jax.named_scope(scopes.EVENTS):
+        old_inc = include_mask(cfg, bundle.state)
     update = (tm.update_batch_parallel if parallel
               else tm.update_batch_sequential)
-    new_state = update(cfg, bundle.state, xs, ys, rng, mask=mask)
-    buf = indexing.events_from_transition(
-        old_inc, include_mask(cfg, new_state), max_events)
-    return sync_caches(bundle, new_state, buf)
+    with jax.named_scope(scopes.FEEDBACK):
+        new_state = update(cfg, bundle.state, xs, ys, rng, mask=mask)
+    caches, buf = replay_events(cfg, bundle.caches, old_inc, new_state,
+                                max_events)
+    overflow = buf.overflow
+    if bundle.event_overflow is not None:
+        overflow = overflow + bundle.event_overflow
+    return TMBundle(cfg=cfg, state=new_state, caches=caches,
+                    event_overflow=overflow, vote_acc=bundle.vote_acc)
 
 
 # Donation updates TA states/caches in place on accelerators; the CPU backend

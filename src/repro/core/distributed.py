@@ -66,9 +66,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core import indexing, tm
+from repro.core import scopes, tm
 from repro.core.api import (
-    DEFAULT_ENGINE, TMBundle, cache_keys_for, resolve_donate)
+    DEFAULT_ENGINE, TMBundle, cache_keys_for, replay_events, resolve_donate)
 from repro.core.engines import CLAUSE_AXIS, cache_provider, get_engine
 from repro.core.types import (
     TMConfig, TMState, VoteAccumulator, clause_polarity, include_mask)
@@ -425,7 +425,6 @@ def make_sharded_train_step(cfg: TMConfig, mesh, *, engines=None,
     """
     geom = geometry(cfg, mesh)
     n_local = geom.n_local
-    keys = cache_keys_for(engines)
     _, cache_specs = bundle_pspecs(cfg, engines)
     all_baxes = batch_axes(mesh)
     d_shards = geom.data_shards
@@ -449,72 +448,93 @@ def make_sharded_train_step(cfg: TMConfig, mesh, *, engines=None,
     y_spec = P(baxes) if baxes else P(None)
     pol = _sharded_polarity(cfg, mesh)
 
-    def local_fn(state_l: TMState, caches_l, pol_l, xs, ys, key_data, mask,
-                 overflow_in):
+    def shard_step(state_l: TMState, caches_l, pol_l, xs, ys, key_data,
+                   mask, stale):
+        # The shard-local step both bodies below share, under the step's
+        # scope names (core/scopes.py): the shard's Type I/II update, then
+        # the events-and-sync tail of api.train_step. ``stale`` (m,) — the
+        # accumulator's read buffer — switches every round to stale-vote
+        # feedback (DESIGN.md §11; the rounds then ignore ``axis_name``
+        # and return per-class vote stats). Returns
+        # (new_state, new_caches, EventBuffer, vote stats or None).
         rng = jax.random.wrap_key_data(key_data)
         start = jax.lax.axis_index(CLAUSE_AXIS) * n_local
-        old_inc = include_mask(cfg, state_l)
+        with jax.named_scope(scopes.EVENTS):
+            old_inc = include_mask(cfg, state_l)
         # validity of this shard's local rows: only the trailing shard(s)
         # carry global clause-axis padding; None when the layout is exact
         # (keeps the even-geometry HLO identical to the pre-ragged path)
         local_valid = None
         if geom.ragged_clauses:
             local_valid = (start + jnp.arange(n_local)) < cfg.n_clauses
-        if parallel:
-            b_idx = jnp.int32(0)
-            for a in baxes:
-                b_idx = b_idx * mesh.shape[a] + jax.lax.axis_index(a)
-            b_total = (xs.shape[0] * math.prod(mesh.shape[a] for a in baxes)
-                       if baxes else None)
-            new_state = tm.update_batch_parallel(
-                cfg, state_l, xs, ys, rng, pol=pol_l, axis_name=CLAUSE_AXIS,
-                clause_start=start, batch_axes=baxes,
-                batch_start=b_idx * xs.shape[0], batch_total=b_total,
-                mask=mask, clause_mask=local_valid)
-        elif compose:
-            # this data rank owns clause rows [d·n_sub, (d+1)·n_sub) of the
-            # model shard's (sub-slice-padded) slice; votes psum over
-            # (data axes + clause axis)
-            d_idx = jnp.int32(0)
-            for a in all_baxes:
-                d_idx = d_idx * mesh.shape[a] + jax.lax.axis_index(a)
-            off = d_idx * n_sub
-            ta_pad = _pad_rows(state_l.ta_state, 1, n_sub_pad, cfg.n_states)
-            pol_pad = _pad_rows(pol_l, 0, n_sub_pad, 0)
-            sub = TMState(ta_state=jax.lax.dynamic_slice_in_dim(
-                ta_pad, off, n_sub, 1))
-            pol_sub = jax.lax.dynamic_slice_in_dim(pol_pad, off, n_sub, 0)
-            sub_valid = None
-            if geom.composition == COMPOSED_RAGGED or geom.ragged_clauses:
-                rows = off + jnp.arange(n_sub)
-                sub_valid = ((rows < n_local)
-                             & ((start + rows) < cfg.n_clauses))
-            new_sub = tm.update_batch_sequential(
-                cfg, sub, xs, ys, rng, pol=pol_sub,
-                axis_name=(*all_baxes, CLAUSE_AXIS),
-                clause_start=start + off, mask=mask, clause_mask=sub_valid)
-            # reassemble the model shard's slice: each real row is owned by
-            # exactly one data rank, so a zero-padded psum is a gather
-            # expressed as the one collective kind this step allows; the
-            # trailing sub-slice padding rows land past n_local and are
-            # dropped by the slice
-            zeros = jnp.zeros(
-                (state_l.ta_state.shape[0], n_sub_pad,
-                 state_l.ta_state.shape[2]), state_l.ta_state.dtype)
-            assembled = jax.lax.dynamic_update_slice_in_dim(
-                zeros, new_sub.ta_state, off, 1)
-            summed = jax.lax.psum(assembled, all_baxes)
-            new_state = TMState(
-                ta_state=jax.lax.slice_in_dim(summed, 0, n_local, axis=1))
-        else:
-            new_state = tm.update_batch_sequential(
-                cfg, state_l, xs, ys, rng, pol=pol_l, axis_name=CLAUSE_AXIS,
-                clause_start=start, mask=mask, clause_mask=local_valid)
-        buf = indexing.events_from_transition(
-            old_inc, include_mask(cfg, new_state), max_events)
-        new_caches = {k: cache_provider(k).update_cache(
-                          cfg, caches_l[k], new_state, buf.events)
-                      for k in keys}
+        with jax.named_scope(scopes.FEEDBACK):
+            if parallel:
+                b_idx = jnp.int32(0)
+                for a in baxes:
+                    b_idx = b_idx * mesh.shape[a] + jax.lax.axis_index(a)
+                b_total = (xs.shape[0]
+                           * math.prod(mesh.shape[a] for a in baxes)
+                           if baxes else None)
+                out = tm.update_batch_parallel(
+                    cfg, state_l, xs, ys, rng, pol=pol_l,
+                    axis_name=CLAUSE_AXIS, clause_start=start,
+                    batch_axes=baxes, batch_start=b_idx * xs.shape[0],
+                    batch_total=b_total, mask=mask, clause_mask=local_valid,
+                    stale_votes=stale)
+            elif compose:
+                # this data rank owns clause rows [d·n_sub, (d+1)·n_sub) of
+                # the model shard's (sub-slice-padded) slice; votes psum
+                # over (data axes + clause axis)
+                d_idx = jnp.int32(0)
+                for a in all_baxes:
+                    d_idx = d_idx * mesh.shape[a] + jax.lax.axis_index(a)
+                off = d_idx * n_sub
+                ta_pad = _pad_rows(state_l.ta_state, 1, n_sub_pad,
+                                   cfg.n_states)
+                pol_pad = _pad_rows(pol_l, 0, n_sub_pad, 0)
+                sub = TMState(ta_state=jax.lax.dynamic_slice_in_dim(
+                    ta_pad, off, n_sub, 1))
+                pol_sub = jax.lax.dynamic_slice_in_dim(pol_pad, off, n_sub, 0)
+                sub_valid = None
+                if geom.composition == COMPOSED_RAGGED or geom.ragged_clauses:
+                    rows = off + jnp.arange(n_sub)
+                    sub_valid = ((rows < n_local)
+                                 & ((start + rows) < cfg.n_clauses))
+                out = tm.update_batch_sequential(
+                    cfg, sub, xs, ys, rng, pol=pol_sub,
+                    axis_name=(*all_baxes, CLAUSE_AXIS),
+                    clause_start=start + off, mask=mask,
+                    clause_mask=sub_valid, stale_votes=stale)
+            else:
+                out = tm.update_batch_sequential(
+                    cfg, state_l, xs, ys, rng, pol=pol_l,
+                    axis_name=CLAUSE_AXIS, clause_start=start, mask=mask,
+                    clause_mask=local_valid, stale_votes=stale)
+            new_state, stats = out if stale is not None else (out, None)
+            if compose:
+                # reassemble the model shard's slice: each real row is owned
+                # by exactly one data rank, so a zero-padded psum is a
+                # gather expressed as the one collective kind this step
+                # allows (async too: state composition must be exact, only
+                # the vote feedback term may go stale); the trailing
+                # sub-slice padding rows land past n_local and are dropped
+                # by the slice
+                zeros = jnp.zeros(
+                    (state_l.ta_state.shape[0], n_sub_pad,
+                     state_l.ta_state.shape[2]), state_l.ta_state.dtype)
+                assembled = jax.lax.dynamic_update_slice_in_dim(
+                    zeros, new_state.ta_state, off, 1)
+                summed = jax.lax.psum(assembled, all_baxes)
+                new_state = TMState(ta_state=jax.lax.slice_in_dim(
+                    summed, 0, n_local, axis=1))
+        new_caches, buf = replay_events(cfg, caches_l, old_inc, new_state,
+                                        max_events)
+        return new_state, new_caches, buf, stats
+
+    def local_fn(state_l: TMState, caches_l, pol_l, xs, ys, key_data, mask,
+                 overflow_in):
+        new_state, new_caches, buf, _ = shard_step(
+            state_l, caches_l, pol_l, xs, ys, key_data, mask, None)
         # per-shard drop counts add over the clause axis (each model shard
         # diffs only its own include slice; data ranks see identical diffs),
         # yielding the replicated global overflow counter — an all-reduce,
@@ -524,66 +544,12 @@ def make_sharded_train_step(cfg: TMConfig, mesh, *, engines=None,
 
     def local_fn_async(state_l: TMState, caches_l, pol_l, acc_l, xs, ys,
                        key_data, mask):
-        # Same shard-local structure as local_fn, with the vote psum (and
-        # the per-step overflow psum) deleted: rounds read the accumulator's
+        # Same shard-local step as local_fn, with the vote psum (and the
+        # per-step overflow psum) deleted: rounds read the accumulator's
         # stale remote term, vote/overflow stats land in the write buffer.
-        rng = jax.random.wrap_key_data(key_data)
-        start = jax.lax.axis_index(CLAUSE_AXIS) * n_local
-        old_inc = include_mask(cfg, state_l)
-        stale = acc_l.stale[0]  # (m,) — this rank's read buffer
-        local_valid = None
-        if geom.ragged_clauses:
-            local_valid = (start + jnp.arange(n_local)) < cfg.n_clauses
-        if parallel:
-            b_idx = jnp.int32(0)
-            for a in baxes:
-                b_idx = b_idx * mesh.shape[a] + jax.lax.axis_index(a)
-            b_total = (xs.shape[0] * math.prod(mesh.shape[a] for a in baxes)
-                       if baxes else None)
-            new_state, (vs, vc) = tm.update_batch_parallel(
-                cfg, state_l, xs, ys, rng, pol=pol_l,
-                clause_start=start, batch_axes=baxes,
-                batch_start=b_idx * xs.shape[0], batch_total=b_total,
-                mask=mask, clause_mask=local_valid, stale_votes=stale)
-        elif compose:
-            d_idx = jnp.int32(0)
-            for a in all_baxes:
-                d_idx = d_idx * mesh.shape[a] + jax.lax.axis_index(a)
-            off = d_idx * n_sub
-            ta_pad = _pad_rows(state_l.ta_state, 1, n_sub_pad, cfg.n_states)
-            pol_pad = _pad_rows(pol_l, 0, n_sub_pad, 0)
-            sub = TMState(ta_state=jax.lax.dynamic_slice_in_dim(
-                ta_pad, off, n_sub, 1))
-            pol_sub = jax.lax.dynamic_slice_in_dim(pol_pad, off, n_sub, 0)
-            sub_valid = None
-            if geom.composition == COMPOSED_RAGGED or geom.ragged_clauses:
-                rows = off + jnp.arange(n_sub)
-                sub_valid = ((rows < n_local)
-                             & ((start + rows) < cfg.n_clauses))
-            new_sub, (vs, vc) = tm.update_batch_sequential(
-                cfg, sub, xs, ys, rng, pol=pol_sub,
-                clause_start=start + off, mask=mask, clause_mask=sub_valid,
-                stale_votes=stale)
-            # the reassembly psum stays: state composition must be exact —
-            # only the vote *feedback term* is allowed to go stale
-            zeros = jnp.zeros(
-                (state_l.ta_state.shape[0], n_sub_pad,
-                 state_l.ta_state.shape[2]), state_l.ta_state.dtype)
-            assembled = jax.lax.dynamic_update_slice_in_dim(
-                zeros, new_sub.ta_state, off, 1)
-            summed = jax.lax.psum(assembled, all_baxes)
-            new_state = TMState(
-                ta_state=jax.lax.slice_in_dim(summed, 0, n_local, axis=1))
-        else:
-            new_state, (vs, vc) = tm.update_batch_sequential(
-                cfg, state_l, xs, ys, rng, pol=pol_l,
-                clause_start=start, mask=mask, clause_mask=local_valid,
-                stale_votes=stale)
-        buf = indexing.events_from_transition(
-            old_inc, include_mask(cfg, new_state), max_events)
-        new_caches = {k: cache_provider(k).update_cache(
-                          cfg, caches_l[k], new_state, buf.events)
-                      for k in keys}
+        new_state, new_caches, buf, (vs, vc) = shard_step(
+            state_l, caches_l, pol_l, xs, ys, key_data, mask,
+            acc_l.stale[0])
         # write buffer: batch-mean local partial votes per touched class
         # (untouched classes keep their previous estimate); overflow counts
         # accumulate per rank and drain at the next refresh collective

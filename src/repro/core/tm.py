@@ -19,6 +19,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import scopes
 from repro.core.types import (
     TMConfig,
     TMState,
@@ -267,18 +268,18 @@ def update_sample(
     ignored for the vote in this mode.
     """
     lit = literals_from_input(x)
-    k_neg, k_a, k_b = jax.random.split(rng, 3)
-    # sample negative class ≠ y
-    neg = jax.random.randint(k_neg, (), 0, cfg.n_classes - 1)
-    neg = jnp.where(neg >= y, neg + 1, neg)
-
     ta = state.ta_state
-    rands_a = draw_feedback_rands(cfg, k_a)
-    rands_b = draw_feedback_rands(cfg, k_b)
-    if clause_start is not None:
-        n_local = ta.shape[1]
-        rands_a = _slice_rands(rands_a, clause_start, n_local)
-        rands_b = _slice_rands(rands_b, clause_start, n_local)
+    with jax.named_scope(scopes.DRAWS):
+        k_neg, k_a, k_b = jax.random.split(rng, 3)
+        # sample negative class ≠ y
+        neg = jax.random.randint(k_neg, (), 0, cfg.n_classes - 1)
+        neg = jnp.where(neg >= y, neg + 1, neg)
+        rands_a = draw_feedback_rands(cfg, k_a)
+        rands_b = draw_feedback_rands(cfg, k_b)
+        if clause_start is not None:
+            n_local = ta.shape[1]
+            rands_a = _slice_rands(rands_a, clause_start, n_local)
+            rands_b = _slice_rands(rands_b, clause_start, n_local)
     if stale_votes is not None:
         row_pos, v_pos = _class_round(
             cfg, ta[y], lit, rands_a, jnp.asarray(True), pol=pol,

@@ -1,0 +1,120 @@
+"""Every train step carries the four phase scopes (``core/scopes.py``).
+
+A device profile attributes an op's time to a phase by the scope in the
+op's ``op_name``, so a refactor that drops a scope fails here instead of
+quietly leaving a phase unnamed. Each step is lowered and compiled for a
+tiny config, and each scope must appear in the ``op_name`` metadata of the
+compiled HLO: the single-device step in both learning
+modes, and both shard-local bodies (sync and stale-vote) of the sharded
+step in both modes, on a forced 4-device host mesh (data=2 × model=2, so
+the sequential step composes data × clause).
+"""
+import json
+import os
+import re
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
+
+import pytest
+
+from repro.core import scopes
+
+SRC = str(Path(__file__).resolve().parents[1] / "src")
+SCOPES = (scopes.FEEDBACK, scopes.DRAWS, scopes.EVENTS, scopes.CACHE_SYNC)
+
+
+def scopes_in(names) -> list[str]:
+    """The scopes that name a segment of some op's name stack, bare or
+    under a transform (``vmap(tm.draws)``)."""
+    return [s for s in SCOPES
+            if any(re.search(rf"(^|/)(\w+\()*{re.escape(s)}\)*(/|$)", n)
+                   for n in names)]
+
+
+def op_names(hlo: str) -> set[str]:
+    return set(re.findall(r'op_name="([^"]*)"', hlo))
+
+
+def test_scope_names_are_distinct_and_namespaced():
+    assert len(set(SCOPES)) == 4
+    assert all(s.startswith("tm.") for s in SCOPES)
+
+
+@pytest.mark.parametrize("parallel", [False, True],
+                         ids=["sequential", "parallel"])
+def test_single_device_step_carries_every_scope(parallel):
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import TMConfig, init_bundle, train_step
+
+    cfg = TMConfig(n_classes=3, n_clauses=8, n_features=6, n_states=20,
+                   s=3.0, threshold=4)
+    bundle = init_bundle(cfg, engines=("bitpack", "indexed"),
+                         rng=jax.random.key(0))
+    xs = jnp.zeros((4, 6), jnp.uint8)
+    ys = jnp.zeros((4,), jnp.int32)
+    step = jax.jit(train_step, static_argnames=("parallel", "max_events"))
+    hlo = step.lower(bundle, xs, ys, jax.random.key(1), None,
+                     parallel=parallel, max_events=32).compile().as_text()
+    assert scopes_in(op_names(hlo)) == list(SCOPES)
+
+
+SHARDED = textwrap.dedent("""
+    import json, os, re
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, jax.numpy as jnp
+    from repro.core import TMConfig, init_tm
+    from repro.core.distributed import (
+        make_sharded_prepare, make_sharded_train_step)
+    from repro.launch.mesh import make_host_mesh
+
+    cfg = TMConfig(n_classes=3, n_clauses=8, n_features=6, n_states=20,
+                   s=3.0, threshold=4)
+    engines = ("bitpack", "indexed")
+    mesh = make_host_mesh(data=2, model=2)
+    state = init_tm(cfg, jax.random.key(0))
+    xs = jnp.zeros((4, 6), jnp.uint8)
+    ys = jnp.zeros((4,), jnp.int32)
+    key = jax.random.key_data(jax.random.key(1))
+    mask = jnp.ones((4,), bool)
+    out = {}
+    for parallel in (False, True):
+        for k in (0, 2):
+            step = make_sharded_train_step(
+                cfg, mesh, engines=engines, parallel=parallel,
+                max_events=32, async_votes=k)
+            b = make_sharded_prepare(cfg, mesh, engines=engines,
+                                     async_votes=k)(state)
+            if k:
+                args = (b.state, b.caches, step.pol, b.vote_acc, xs, ys,
+                        key, mask)
+            else:
+                args = (b.state, b.caches, step.pol, xs, ys, key, mask,
+                        jnp.zeros((), jnp.int32))
+            hlo = step.jitted.lower(*args).compile().as_text()
+            name = (("parallel" if parallel else "sequential")
+                    + ("_async" if k else "_sync"))
+            out[name] = sorted(set(re.findall(r'op_name="([^"]*)"', hlo)))
+    print("OPNAMES " + json.dumps(out))
+""")
+
+
+@pytest.fixture(scope="module")
+def sharded_op_names():
+    res = subprocess.run(
+        [sys.executable, "-c", SHARDED],
+        env={**os.environ, "PYTHONPATH": SRC, "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True, timeout=600)
+    assert res.returncode == 0, res.stderr[-3000:]
+    line = [ln for ln in res.stdout.splitlines()
+            if ln.startswith("OPNAMES ")][-1]
+    return json.loads(line[len("OPNAMES "):])
+
+
+@pytest.mark.parametrize("body", ["sequential_sync", "sequential_async",
+                                  "parallel_sync", "parallel_async"])
+def test_sharded_step_bodies_carry_every_scope(sharded_op_names, body):
+    assert scopes_in(sharded_op_names[body]) == list(SCOPES)

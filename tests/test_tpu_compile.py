@@ -12,6 +12,7 @@ only one process at a time may load the TPU compiler library, and every
 test worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.tm import imdb_like, mnist_like
+from repro.core import TMConfig, init_bundle, scopes, train_step
 from repro.kernels import clause_eval, indexed, ta_update
 
 M1 = mnist_like(1).tm
@@ -87,3 +89,37 @@ def test_kernel_compiles_for_v5e(one_chip, name, width):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+
+def test_train_step_phases_keep_their_names_for_v5e(one_chip):
+    """The chip's compiler keeps the step's phase scopes
+    (``core/scopes.py``) in the compiled program's op names, and the
+    scan of rounds is the one loop of the step, under ``tm.feedback``.
+    Sequential learning, bitpack cache, Pallas rounds, the worst-case
+    event buffer, a small width."""
+    cfg = TMConfig(n_classes=2, n_clauses=64, n_features=256, n_states=127,
+                   s=27.0, threshold=40, backend="pallas")
+    batch = 8
+
+    def place(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    bundle = jax.tree.map(place, jax.eval_shape(
+        lambda: init_bundle(cfg, engines=("bitpack",),
+                            rng=jax.random.key(0))))
+    xs = place(jax.ShapeDtypeStruct((batch, cfg.n_features), jnp.uint8))
+    ys = place(jax.ShapeDtypeStruct((batch,), jnp.int32))
+    key = place(jax.eval_shape(lambda: jax.random.key(0)))
+    max_events = 2 * cfg.n_clauses * cfg.n_literals
+    hlo = jax.jit(train_step, static_argnames=("parallel", "max_events")
+                  ).lower(bundle, xs, ys, key, None, parallel=False,
+                          max_events=max_events).compile().as_text()
+    segments = {seg for name in re.findall(r'op_name="([^"]*)"', hlo)
+                for seg in name.split("/")}
+    assert {scopes.FEEDBACK, scopes.DRAWS, scopes.EVENTS,
+            scopes.CACHE_SYNC} <= segments
+    entry = hlo[hlo.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}\n")]
+    loops = re.findall(r' while\(.*op_name="([^"]*)"', entry)
+    assert loops == [f"jit(train_step)/{scopes.FEEDBACK}/while"]
