@@ -261,6 +261,15 @@ def events_from_transition(
     changed cells in ascending cell order, then ascending unchanged fill —
     at O(cells) work instead of a full O(cells·log) sort every train step
     (regression-pinned in tests/test_tm_indexing.py).
+
+    The buffer is built without gathers, from that one scatter and
+    elementwise ops. ``valid`` needs no look-up: the slot map is a
+    bijection that gives changed cells the slots ``0..total-1``, so slot
+    s holds a changed cell exactly when ``s < total``. ``is_insert`` is a
+    property of the cell, so it travels with the cell's index through the
+    scatter as the ``uint32`` payload ``cell·2 + new_bit``. The ``int32``
+    cell index addresses at most 2³¹ − 1 cells, so the payload stays below
+    2³² and cannot overflow.
     """
     changed = old_include != new_include                 # (m, n, 2o)
     flat = changed.reshape(-1)
@@ -272,12 +281,15 @@ def events_from_transition(
     ranks = jnp.cumsum(flat.astype(jnp.int32)) - 1       # rank among changed
     pad_ranks = total + jnp.cumsum((~flat).astype(jnp.int32)) - 1
     slot = jnp.where(flat, ranks, pad_ranks)             # bijection on cells
-    sel = jnp.zeros((max_events,), jnp.int32).at[slot].set(
-        jnp.arange(flat.shape[0], dtype=jnp.int32), mode="drop")
-    valid = flat[sel]
+    payload = (jnp.arange(flat.shape[0], dtype=jnp.uint32) * 2
+               + new_include.reshape(-1).astype(jnp.uint32))
+    packed = jnp.zeros((max_events,), jnp.uint32).at[slot].set(
+        payload, mode="drop")
+    sel = (packed >> 1).astype(jnp.int32)
+    is_insert = (packed & 1) == 1
+    valid = jnp.arange(max_events, dtype=jnp.int32) < total
     cls, rem = jnp.divmod(sel, n * L)
     clause, literal = jnp.divmod(rem, L)
-    is_insert = new_include.reshape(-1)[sel]
     return EventBuffer(
         events=Event(
             cls=cls.astype(jnp.int32),

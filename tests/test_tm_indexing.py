@@ -1,4 +1,6 @@
 """Clause index (paper §3): O(1) maintenance, inference equivalence."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -372,17 +374,37 @@ def _events_argsort_reference(old_inc, new_inc, max_events):
             flat[sel], overflow)
 
 
-@pytest.mark.parametrize("seed,max_events", [
-    (0, 64),        # room to spare: changed cells + unchanged fill
-    (1, 16),        # tight
-    (2, 5),         # overflow: more changed cells than buffer slots
-    (3, 10_000),    # buffer larger than the cell count (degenerates to all)
+CELLS = CFG.n_classes * CFG.n_clauses * CFG.n_literals
+
+
+@pytest.mark.parametrize("seed,max_events,change", [
+    # room to spare: changed cells + unchanged fill
+    pytest.param(0, 64, "random", id="0-64"),
+    # tight
+    pytest.param(1, 16, "random", id="1-16"),
+    # overflow: more changed cells than buffer slots
+    pytest.param(2, 5, "random", id="2-5"),
+    # buffer larger than the cell count (degenerates to all)
+    pytest.param(3, 10_000, "random", id="3-10000"),
+    # no cell changed: every slot is unchanged fill, none valid
+    pytest.param(4, 64, "none", id="none-changed"),
+    # every cell changed, buffer of the cell count: every slot valid
+    pytest.param(5, CELLS, "all", id="all-changed"),
+    # every cell changed, overflowing buffer
+    pytest.param(6, 16, "all", id="all-changed-overflow"),
+    # buffer exactly the cell count (the benchmark's worst case)
+    pytest.param(7, CELLS, "random", id="cells-exact"),
+    # buffer of one slot
+    pytest.param(8, 1, "random", id="one-slot"),
+    pytest.param(9, 1, "none", id="one-slot-none-changed"),
 ])
-def test_events_from_transition_matches_argsort_reference(seed, max_events):
+def test_events_from_transition_matches_argsort_reference(seed, max_events,
+                                                          change):
     state0 = random_state(CFG, seed)
     state1 = random_state(CFG, 70 + seed)
     old_inc = include_mask(CFG, state0)
-    new_inc = include_mask(CFG, state1)
+    new_inc = {"random": include_mask(CFG, state1), "none": old_inc,
+               "all": ~old_inc}[change]
     buf = events_from_transition(old_inc, new_inc, max_events)
     cls, clause, literal, is_insert, valid, overflow = \
         _events_argsort_reference(old_inc, new_inc, max_events)
@@ -392,6 +414,18 @@ def test_events_from_transition_matches_argsort_reference(seed, max_events):
     np.testing.assert_array_equal(np.asarray(buf.events.is_insert), is_insert)
     np.testing.assert_array_equal(np.asarray(buf.events.valid), valid)
     assert int(buf.overflow) == overflow
+
+
+@pytest.mark.parametrize("max_events", [16, CELLS])
+def test_events_from_transition_uses_no_gather(max_events):
+    """The buffer is one scatter and elementwise ops: neither the lowered
+    program nor the CPU compile of it holds a gather."""
+    inc = jax.ShapeDtypeStruct(
+        (CFG.n_classes, CFG.n_clauses, CFG.n_literals), jnp.bool_)
+    lowered = jax.jit(events_from_transition, static_argnums=2).lower(
+        inc, inc, max_events)
+    assert not re.search(r"stablehlo\.\w*gather", lowered.as_text())
+    assert not re.search(r"\sgather\(", lowered.compile().as_text())
 
 
 def test_index_sync_through_learning():

@@ -92,12 +92,11 @@ def test_kernel_compiles_for_v5e(one_chip, name, width):
 
 
 
-def test_train_step_phases_keep_their_names_for_v5e(one_chip):
-    """The chip's compiler keeps the step's phase scopes
-    (``core/scopes.py``) in the compiled program's op names, and the
-    scan of rounds is the one loop of the step, under ``tm.feedback``.
-    Sequential learning, bitpack cache, Pallas rounds, the worst-case
-    event buffer, a small width."""
+@pytest.fixture(scope="module")
+def v5e_train_step_hlo(one_chip):
+    """The compiled v5e program of one train step: sequential learning,
+    bitpack cache, Pallas rounds, the worst-case event buffer, a small
+    width."""
     cfg = TMConfig(n_classes=2, n_clauses=64, n_features=256, n_states=127,
                    s=27.0, threshold=40, backend="pallas")
     batch = 8
@@ -112,9 +111,16 @@ def test_train_step_phases_keep_their_names_for_v5e(one_chip):
     ys = place(jax.ShapeDtypeStruct((batch,), jnp.int32))
     key = place(jax.eval_shape(lambda: jax.random.key(0)))
     max_events = 2 * cfg.n_clauses * cfg.n_literals
-    hlo = jax.jit(train_step, static_argnames=("parallel", "max_events")
-                  ).lower(bundle, xs, ys, key, None, parallel=False,
-                          max_events=max_events).compile().as_text()
+    return jax.jit(train_step, static_argnames=("parallel", "max_events")
+                   ).lower(bundle, xs, ys, key, None, parallel=False,
+                           max_events=max_events).compile().as_text()
+
+
+def test_train_step_phases_keep_their_names_for_v5e(v5e_train_step_hlo):
+    """The chip's compiler keeps the step's phase scopes
+    (``core/scopes.py``) in the compiled program's op names, and the
+    scan of rounds is the one loop of the step, under ``tm.feedback``."""
+    hlo = v5e_train_step_hlo
     segments = {seg for name in re.findall(r'op_name="([^"]*)"', hlo)
                 for seg in name.split("/")}
     assert {scopes.FEEDBACK, scopes.DRAWS, scopes.EVENTS,
@@ -123,3 +129,11 @@ def test_train_step_phases_keep_their_names_for_v5e(one_chip):
     entry = entry[:entry.index("\n}\n")]
     loops = re.findall(r' while\(.*op_name="([^"]*)"', entry)
     assert loops == [f"jit(train_step)/{scopes.FEEDBACK}/while"]
+
+
+def test_event_selection_has_no_gather_for_v5e(v5e_train_step_hlo):
+    """Event selection compiles for the chip without a gather: no gather
+    instruction, fused or not, has an op name under ``tm.events``."""
+    names = re.findall(r' gather\(.*op_name="([^"]*)"', v5e_train_step_hlo)
+    assert not [name for name in names
+                if scopes.EVENTS in name.split("/")]
