@@ -20,10 +20,12 @@ recipe over the PR-1 engine registry, so the sharded unit is the whole
     then diffs the *local* include mask and replays the events into the
     shard-local caches: event-driven cache sync never leaves the shard.
 
-Randomness: every shard draws the identical full-size uniforms and slices
-its clause rows (``tm._slice_rands``), so sharded training is **bit-exact**
-with the single-device path — the property tests/test_tm_sharded.py pins
-for every registered engine on a forced 8-device host mesh.
+Randomness: each shard draws only its own clause rows of the same uniform
+stream (``tm.uniform_rows``: the counters of the full draw's rows, not the
+full draw), so no chip produces another chip's uniforms and sharded
+training stays **bit-exact** with the single-device path — the property
+tests/test_tm_sharded.py pins for every registered engine on a forced
+8-device host mesh.
 
 Ragged geometry (DESIGN.md §9): *any* ``(data_shards, clause_shards,
 n_clauses)`` is a first-class topology. The clause axis pads up to
@@ -404,8 +406,8 @@ def make_sharded_train_step(cfg: TMConfig, mesh, *, engines=None,
     deltas. Either way every collective is an all-reduce; the include-mask
     diff and every cache's event replay stay on the model shard
     (``max_events`` bounds the *per-shard* event buffer). Bit-exact with
-    the single-device ``api.train_step`` (identical randomness via
-    full-draw slicing).
+    the single-device ``api.train_step`` (identical randomness: each shard
+    draws its own rows of the same stream).
 
     ``mask`` (B,) bool marks valid samples (the fixed-shape padding
     contract of ``api.train_step``); omitted → all rows valid. The fired

@@ -14,8 +14,9 @@ was a single front door. This module is that door:
     or the shard_map path (``distributed.make_sharded_*`` over a host mesh),
     and exposes placement-transparent ``prepare`` / ``train_step`` /
     ``scores`` / ``predict``. Both resolutions are bit-exact for the same
-    seed (full-draw rand slicing), so a topology is a deployment detail —
-    the property tests/test_tm_session.py pins.
+    seed (each shard draws its own rows of the same stream), so a
+    topology is a deployment detail — the property tests/test_tm_session.py
+    pins.
   * ``TsetlinMachine`` — the stateful estimator facade over a session
     (init / fit / partial_fit / predict / scores / evaluate, plus the
     versioned ``save`` / ``load`` checkpoint API). ``fit`` pads a trailing

@@ -94,35 +94,76 @@ class FeedbackRands(NamedTuple):
     type_i: jax.Array       # (n, 2o)   uniforms vs 1/s and (s-1)/s
 
 
-def draw_feedback_rands(cfg: TMConfig, rng: jax.Array) -> FeedbackRands:
-    """Draw one class-round's full-size uniforms from ``rng``."""
-    k1, k2 = jax.random.split(rng)
-    return FeedbackRands(
-        clause_gate=jax.random.uniform(k1, (cfg.n_clauses,)),
-        type_i=jax.random.uniform(k2, (cfg.n_clauses, cfg.n_literals)),
-    )
+def draw_feedback_rands(cfg: TMConfig, rng: jax.Array,
+                        clause_start: jax.Array | None = None,
+                        n_rows: int | None = None) -> FeedbackRands:
+    """Draw one class-round's uniforms from ``rng``.
 
-
-def _slice_rands(rands: FeedbackRands, start: jax.Array,
-                 n_local: int) -> FeedbackRands:
-    """Clause-shard slice of a *full* draw (clause-sharded learning).
-
-    Every shard materialises the identical full-size draw and takes its own
-    row block — the only scheme that keeps sharded learning bit-exact with
-    the single-device path (per-shard draws would consume different keys).
-
-    The row indices clamp into the draw instead of dynamic-slicing it, so a
-    *padded* slice (ragged data×clause sub-slices, DESIGN.md §9) whose tail
-    rows fall past ``n_clauses`` still reads the exact draw rows for its
-    real clauses; the clamped duplicates land only on padding rows, whose
-    updates are masked out (``clause_mask``).
+    With ``clause_start`` None: the full ``(n_clauses,)`` gate and
+    ``(n_clauses, 2o)`` Type I uniforms. Otherwise only clause rows
+    ``[clause_start, clause_start + n_rows)`` of those same draws, bit for
+    bit (``uniform_rows``): a clause shard draws its own rows and nothing
+    else. Padding rows of a ragged slice (DESIGN.md §9) get other values
+    of the stream; ``clause_mask`` freezes them.
     """
-    idx = jnp.clip(start + jnp.arange(n_local),
-                   0, rands.clause_gate.shape[0] - 1)
+    k1, k2 = jax.random.split(rng)
+    if clause_start is None:
+        return FeedbackRands(
+            clause_gate=jax.random.uniform(k1, (cfg.n_clauses,)),
+            type_i=jax.random.uniform(k2, (cfg.n_clauses, cfg.n_literals)),
+        )
     return FeedbackRands(
-        clause_gate=jnp.take(rands.clause_gate, idx, axis=0),
-        type_i=jnp.take(rands.type_i, idx, axis=0),
+        clause_gate=uniform_rows(k1, clause_start, n_rows, 1)[:, 0],
+        type_i=uniform_rows(k2, clause_start, n_rows, cfg.n_literals),
     )
+
+
+def flat_counters(start: jax.Array, n_rows: int,
+                  width: int) -> tuple[jax.Array, jax.Array]:
+    """The 64-bit row-major flat index ``(start + r)·width + c`` of an
+    ``(n_rows, width)`` block, as (high, low) uint32 words (no x64 needed).
+
+    The row's product with ``width`` is formed from 16-bit halves, so it is
+    exact for any row and width below 2³²; the column add carries into the
+    high word."""
+    row = (jnp.asarray(start).astype(jnp.uint32)
+           + jnp.arange(n_rows, dtype=jnp.uint32))
+    r_lo, r_hi = row & 0xFFFF, row >> 16
+    w_lo, w_hi = jnp.uint32(width & 0xFFFF), jnp.uint32(width >> 16)
+    p0, p1, p2 = r_lo * w_lo, r_lo * w_hi, r_hi * w_lo
+    mid = (p0 >> 16) + (p1 & 0xFFFF) + (p2 & 0xFFFF)
+    lo = (p0 & 0xFFFF) | (mid << 16)
+    hi = r_hi * w_hi + (p1 >> 16) + (p2 >> 16) + (mid >> 16)
+    flat_lo = lo[:, None] + jnp.arange(width, dtype=jnp.uint32)[None, :]
+    flat_hi = hi[:, None] + (flat_lo < lo[:, None]).astype(jnp.uint32)
+    return flat_hi, flat_lo
+
+
+def uniform_rows(key: jax.Array, start: jax.Array, n_rows: int,
+                 width: int) -> jax.Array:
+    """Rows ``[start, start + n_rows)`` of ``jax.random.uniform(key,
+    (N, width))`` for any ``N >= start + n_rows``, bit for bit, without
+    drawing the other rows.
+
+    Under JAX's partitionable threefry (``jax_threefry_partitionable``, on
+    by default) element ``(i, j)`` of a draw is threefry-2x32 of the key and
+    the flat index ``i·width + j``, and ``uniform`` keeps the top 23 bits as
+    the mantissa of a float in [1, 2) less 1. This function builds those
+    counters for its rows alone and converts them the same way.
+    ``tests/test_tm_core.py`` pins the layout against ``jax.random.uniform``
+    so that a JAX release that changes it fails there.
+    """
+    from jax.extend.random import threefry2x32_p
+
+    if not jax.config.jax_threefry_partitionable:
+        raise RuntimeError("uniform_rows reproduces the partitionable "
+                           "threefry layout; jax_threefry_partitionable is off")
+    k1, k2 = jax.random.key_data(key)
+    hi, lo = flat_counters(start, n_rows, width)
+    b1, b2 = threefry2x32_p.bind(k1, k2, hi, lo)
+    mantissa = ((b1 ^ b2) >> 9) | jnp.uint32(0x3F800000)
+    floats = jax.lax.bitcast_convert_type(mantissa, jnp.float32) - 1.0
+    return jnp.maximum(0.0, floats)
 
 
 def _round_clause_outputs(cfg: TMConfig, ta_row: jax.Array,
@@ -212,7 +253,8 @@ def _class_round(
     else:
         vote_sum = vote_local
         if axis_name is not None:
-            vote_sum = jax.lax.psum(vote_sum, axis_name)
+            with jax.named_scope(scopes.VOTES):
+                vote_sum = jax.lax.psum(vote_sum, axis_name)
     votes = jnp.clip(vote_sum, -t, t)
     p = jnp.where(positive_round, (t - votes) / (2 * t), (t + votes) / (2 * t))
     active = rands.clause_gate < p                    # (n,)
@@ -254,8 +296,8 @@ def update_sample(
 
     When ``state`` holds only a clause shard, pass the shard's polarity
     slice ``pol``, the mesh clause ``axis_name`` (vote psum) and the shard's
-    global ``clause_start`` (rand slicing) — every shard draws the identical
-    full-size randomness and consumes its own rows, so the sharded update is
+    global ``clause_start`` — each shard draws only its own rows of the
+    same uniform stream (``uniform_rows``), so the sharded update is
     bit-exact with the single-device one. ``clause_mask`` (n,) freezes
     padding rows of a ragged slice (see ``_class_round``).
 
@@ -274,12 +316,9 @@ def update_sample(
         # sample negative class ≠ y
         neg = jax.random.randint(k_neg, (), 0, cfg.n_classes - 1)
         neg = jnp.where(neg >= y, neg + 1, neg)
-        rands_a = draw_feedback_rands(cfg, k_a)
-        rands_b = draw_feedback_rands(cfg, k_b)
-        if clause_start is not None:
-            n_local = ta.shape[1]
-            rands_a = _slice_rands(rands_a, clause_start, n_local)
-            rands_b = _slice_rands(rands_b, clause_start, n_local)
+        n_rows = None if clause_start is None else ta.shape[1]
+        rands_a = draw_feedback_rands(cfg, k_a, clause_start, n_rows)
+        rands_b = draw_feedback_rands(cfg, k_b, clause_start, n_rows)
     if stale_votes is not None:
         row_pos, v_pos = _class_round(
             cfg, ta[y], lit, rands_a, jnp.asarray(True), pol=pol,

@@ -118,3 +118,60 @@ def test_predict_shape_and_range():
     p = predict(CFG, state, xs)
     assert p.shape == (9,)
     assert int(p.min()) >= 0 and int(p.max()) < CFG.n_classes
+
+
+# ---------------------------------------------------------------------------
+# Per-shard draws: a clause shard's rows of the full uniform draw
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n,width,a,b", [
+    (37, 130, 5, 20), (40, 1, 0, 40), (100, 333, 97, 100),
+    (64, 200, 0, 16), (9, 2 * 7, 3, 9)])
+def test_uniform_rows_equal_rows_of_the_full_draw(n, width, a, b):
+    key = jax.random.key(n * 1000 + width)
+    full = jax.random.uniform(key, (n, width))
+    part = tm_mod.uniform_rows(key, jnp.int32(a), b - a, width)
+    assert part.dtype == jnp.float32 and part.shape == (b - a, width)
+    np.testing.assert_array_equal(np.asarray(part), np.asarray(full[a:b]))
+
+
+@pytest.mark.parametrize("a,b", [(0, 8), (2, 6), (5, 8)])
+def test_feedback_rands_of_a_row_block_equal_the_full_draw(a, b):
+    cfg = TMConfig(n_classes=2, n_clauses=8, n_features=67, n_states=50,
+                   s=3.0, threshold=4)          # 2o = 134: not a lane multiple
+    key = jax.random.key(7)
+    full = tm_mod.draw_feedback_rands(cfg, key)
+    rows = tm_mod.draw_feedback_rands(cfg, key, jnp.int32(a), b - a)
+    np.testing.assert_array_equal(np.asarray(rows.clause_gate),
+                                  np.asarray(full.clause_gate[a:b]))
+    np.testing.assert_array_equal(np.asarray(rows.type_i),
+                                  np.asarray(full.type_i[a:b]))
+
+
+@pytest.mark.parametrize("start,width", [
+    (0, 40_000), (107_374, 40_000), (107_375, 40_000), (2**20 + 3, 2**13 + 5),
+    (2**31 - 2, 65_537), (3_000_000_000, 65_537), (4_000_000_000, 7)])
+def test_flat_counters_carry_the_high_word(start, width):
+    n_rows = 3
+    hi, lo = tm_mod.flat_counters(jnp.uint32(start), n_rows, width)
+    rows = np.arange(start, start + n_rows, dtype=np.uint64)
+    want = (rows[:, None] * np.uint64(width)
+            + np.arange(width, dtype=np.uint64))
+    np.testing.assert_array_equal(np.asarray(hi),
+                                  (want >> np.uint64(32)).astype(np.uint32))
+    np.testing.assert_array_equal(np.asarray(lo),
+                                  (want & np.uint64(2**32 - 1))
+                                  .astype(np.uint32))
+
+
+def test_flat_counters_carry_across_a_row_end():
+    # the last columns of a row cross 2**32 inside the row
+    width = 1 << 20
+    start = (1 << 32) // width - 1                  # row ends exactly at 2**32
+    hi, lo = tm_mod.flat_counters(jnp.uint32(start), 2, width)
+    want = (np.arange(start, start + 2, dtype=np.uint64)[:, None]
+            * np.uint64(width) + np.arange(width, dtype=np.uint64))
+    np.testing.assert_array_equal(np.asarray(hi),
+                                  (want >> np.uint64(32)).astype(np.uint32))
+    np.testing.assert_array_equal(np.asarray(lo),
+                                  (want & np.uint64(2**32 - 1)).astype(np.uint32))

@@ -1,4 +1,5 @@
-"""Every train step carries the four phase scopes (``core/scopes.py``).
+"""Every train step carries the four phase scopes (``core/scopes.py``),
+and the sharded sync step names its vote psum ``tm.votes``.
 
 A device profile attributes an op's time to a phase by the scope in the
 op's ``op_name``, so a refactor that drops a scope fails here instead of
@@ -7,7 +8,9 @@ tiny config, and each scope must appear in the ``op_name`` metadata of the
 compiled HLO: the single-device step in both learning
 modes, and both shard-local bodies (sync and stale-vote) of the sharded
 step in both modes, on a forced 4-device host mesh (data=2 × model=2, so
-the sequential step composes data × clause).
+the sequential step composes data × clause). Every all-reduce that the
+per-round vote psum lowers to carries ``tm.votes``; the stale-vote and
+single-device bodies have no vote psum and carry no ``tm.votes``.
 """
 import json
 import os
@@ -38,8 +41,9 @@ def op_names(hlo: str) -> set[str]:
 
 
 def test_scope_names_are_distinct_and_namespaced():
-    assert len(set(SCOPES)) == 4
-    assert all(s.startswith("tm.") for s in SCOPES)
+    names = SCOPES + (scopes.VOTES,)
+    assert len(set(names)) == 5
+    assert all(s.startswith("tm.") for s in names)
 
 
 @pytest.mark.parametrize("parallel", [False, True],
@@ -60,6 +64,19 @@ def test_single_device_step_carries_every_scope(parallel):
     hlo = step.lower(bundle, xs, ys, jax.random.key(1), None,
                      parallel=parallel, max_events=32).compile().as_text()
     assert scopes_in(op_names(hlo)) == list(SCOPES)
+    assert not any(scopes.VOTES in n for n in op_names(hlo))
+    assert not all_reduces(hlo)
+
+
+def all_reduces(hlo: str) -> list[str]:
+    """The ``op_name`` of every all-reduce instruction of a compiled
+    module ("" where it has none)."""
+    out = []
+    for ln in hlo.splitlines():
+        if re.search(r"\ball-reduce(-start)?\(", ln):
+            m = re.search(r'op_name="([^"]*)"', ln)
+            out.append(m.group(1) if m else "")
+    return out
 
 
 SHARDED = textwrap.dedent("""
@@ -98,6 +115,7 @@ SHARDED = textwrap.dedent("""
             name = (("parallel" if parallel else "sequential")
                     + ("_async" if k else "_sync"))
             out[name] = sorted(set(re.findall(r'op_name="([^"]*)"', hlo)))
+            out[name + "_hlo"] = hlo
     print("OPNAMES " + json.dumps(out))
 """)
 
@@ -118,3 +136,22 @@ def sharded_op_names():
                                   "parallel_sync", "parallel_async"])
 def test_sharded_step_bodies_carry_every_scope(sharded_op_names, body):
     assert scopes_in(sharded_op_names[body]) == list(SCOPES)
+
+
+@pytest.mark.parametrize("body", ["sequential_sync", "parallel_sync"])
+def test_vote_psum_all_reduces_carry_the_votes_scope(sharded_op_names, body):
+    ops = all_reduces(sharded_op_names[body + "_hlo"])
+    # the rounds' all-reduces: inside the scan's body, or under the vmap
+    in_rounds = [n for n in ops if "/while/body/" in n or "vmap(" in n]
+    assert len(in_rounds) == 2, ops          # one per class round
+    assert all(re.search(rf"(^|/)(\w+\()*{re.escape(scopes.VOTES)}\)*/", n)
+               for n in in_rounds), in_rounds
+    # the step's other all-reduces (overflow count, state reassembly or
+    # delta sum) are not votes
+    others = [n for n in ops if n not in in_rounds]
+    assert len(others) == 2 and not any(scopes.VOTES in n for n in others)
+
+
+@pytest.mark.parametrize("body", ["sequential_async", "parallel_async"])
+def test_stale_vote_bodies_carry_no_votes_scope(sharded_op_names, body):
+    assert not any(scopes.VOTES in n for n in sharded_op_names[body])

@@ -17,7 +17,14 @@ Registry-driven (subprocess, ``--xla_force_host_platform_device_count=8``):
     and restores **onto a different mesh** (reshard-on-restore: 4 clause
     shards → 2), continuing bit-exactly vs an uninterrupted single-device
     trainer run.
+
+On a forced 4-device mesh (subprocess): ``Topology(clause_shards=4)``,
+whose shards draw only their own rows of the uniform stream, trains
+bit-exactly with ``Topology(1)`` and with the benchmark's plain reference
+(``bench/ref.py``), on an even and a ragged clause count.
 """
+import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -213,3 +220,74 @@ def test_tm_sharded_parity_subprocess():
                    "tm-ragged-prime-ok", "tm-ragged-replicate-ok",
                    "tm-trainer-reshard-ok"):
         assert marker in res.stdout, res.stdout + "\n" + res.stderr
+
+
+# Per-shard draws: each clause shard draws only its own rows of the uniform
+# stream (tm.uniform_rows); training stays bit-exact with one device and with
+# the benchmark's plain reference, which draws the full (n, 2o) uniforms.
+PER_SHARD = textwrap.dedent("""
+    import json, os, sys
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax, jax.numpy as jnp
+    import numpy as np
+    from repro.core import TMConfig, TMState, Topology, TsetlinMachine
+    from bench import ref
+
+    out = {}
+    for n_clauses in (16, 18):        # 4 rows a shard; 5 with 2 padding rows
+        tm = dict(n_classes=3, n_clauses=n_clauses, n_features=35,
+                  n_states=50, s=3.0, threshold=4,
+                  boost_true_positive=False)
+        cfg = TMConfig(**tm)
+        rng = np.random.default_rng(n_clauses)
+        ta0 = jnp.asarray(rng.integers(40, 61, (3, n_clauses, 70)),
+                          jnp.int16)
+        batches = [(rng.integers(0, 2, (6, 35)).astype(np.uint8),
+                    rng.integers(0, 3, 6).astype(np.int32))
+                   for _ in range(3)]
+        keys = [jax.random.key(100 + k) for k in range(3)]
+        states = {}
+        for name, topo in (("one", Topology()),
+                           ("clause4", Topology(clause_shards=4))):
+            machine = TsetlinMachine(cfg, topology=topo,
+                                     engines=("bitpack",),
+                                     max_events_per_batch=3 * 20 * 70)
+            machine.bundle = machine.session.prepare(TMState(ta_state=ta0))
+            got = []
+            for (xs, ys), k in zip(batches, keys):
+                machine.partial_fit(xs, ys, rng=k)
+                got.append(np.asarray(machine.state.ta_state))
+            states[name] = got
+        want = [np.asarray(a) for a in ref.train_steps(ta0, batches, keys, tm)]
+        out[n_clauses] = {
+            "clause4_vs_one": [int((a != b).sum()) for a, b in
+                               zip(states["clause4"], states["one"])],
+            "clause4_vs_ref": [int((a != b).sum()) for a, b in
+                               zip(states["clause4"], want)],
+            "changed": [int((a != np.asarray(ta0)).sum())
+                        for a in states["clause4"]]}
+    print("PERSHARD " + json.dumps(out))
+""")
+
+
+@pytest.fixture(scope="module")
+def per_shard_runs():
+    root = str(Path(__file__).resolve().parents[1])
+    res = subprocess.run(
+        [sys.executable, "-c", PER_SHARD],
+        env={**os.environ, "JAX_PLATFORMS": "cpu",
+             "PYTHONPATH": SRC + ":" + root},
+        capture_output=True, text=True, timeout=600)
+    assert res.returncode == 0, res.stderr[-3000:]
+    line = [ln for ln in res.stdout.splitlines()
+            if ln.startswith("PERSHARD ")][-1]
+    return json.loads(line[len("PERSHARD "):])
+
+
+@pytest.mark.parametrize("n_clauses", ["16", "18"], ids=["even", "ragged"])
+def test_clause4_training_equals_one_device_and_the_reference(
+        per_shard_runs, n_clauses):
+    run = per_shard_runs[n_clauses]
+    assert run["clause4_vs_one"] == [0, 0, 0]
+    assert run["clause4_vs_ref"] == [0, 0, 0]
+    assert all(c > 0 for c in run["changed"])      # the steps learnt

@@ -6,6 +6,9 @@ kernel is compiled with ``interpret=False`` for one chip of a *described*
 ``v5e:2x2`` topology — nothing runs, so no TPU is needed — at M1 width
 (``configs/tm.py`` ``mnist_like(1)``: 10 classes, 2000 clauses, 1568
 literals) and, for ``indexed_votes``, also at I1 width (2o = 10,000).
+The clause-sharded train step compiles for all four chips of the topology
+at I4 with 20,000 clauses (``imdb_like(20000, 20000)``), where each chip
+draws only its own 5,000 clause rows of the uniforms.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU compiler library, and every
@@ -17,7 +20,7 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.configs.tm import imdb_like, mnist_like
 from repro.core import TMConfig, init_bundle, scopes, train_step
@@ -29,7 +32,8 @@ SERVE_BATCH = 32
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e():
+    """The described ``v5e:2x2`` topology (four chips)."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -43,8 +47,13 @@ def one_chip():
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 def _kernel_case(name, cfg):
@@ -137,3 +146,52 @@ def test_event_selection_has_no_gather_for_v5e(v5e_train_step_hlo):
     names = re.findall(r' gather\(.*op_name="([^"]*)"', v5e_train_step_hlo)
     assert not [name for name in names
                 if scopes.EVENTS in name.split("/")]
+
+
+def test_clause4_step_draws_only_its_own_rows_for_v5e(v5e, monkeypatch):
+    """The sequential train step of I4 at 20,000 clauses, sharded by clause
+    over the four chips, compiles for the chip: no op holds the full
+    ``(20000, 40000)`` float32 draw, nothing under ``tm.draws`` gathers,
+    both vote all-reduces of the rounds carry ``tm.votes``, and a chip's
+    arguments and temporaries fit its 16 GB."""
+    import numpy as np
+
+    from repro.core import distributed
+
+    cfg = TMConfig(n_classes=2, n_clauses=20000, n_features=20000,
+                   n_states=127, s=27.0, threshold=40, backend="pallas")
+    m, n, L = cfg.n_classes, cfg.n_clauses, cfg.n_literals
+    batch, shards = 32, 4
+    mesh = Mesh(np.asarray(v5e.devices).reshape(1, shards),
+                ("data", "model"))
+
+    def spec(shape, dtype, *axes):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, PartitionSpec(*axes)))
+
+    # the step places its polarity on the mesh's devices, which are only
+    # described here: hand it the placed shape instead
+    pol = spec((n,), jnp.int32, "model")
+    monkeypatch.setattr(distributed, "_sharded_polarity", lambda *_: pol)
+    step = distributed.make_sharded_train_step(
+        cfg, mesh, engines=("bitpack",),
+        max_events=min(m, 2 * batch) * (n // shards) * L)
+    compiled = step.jitted.lower(
+        distributed.TMState(ta_state=spec((m, n, L), jnp.int16,
+                                          None, "model", None)),
+        {"bitpack": spec((m, n, -(-L // 32)), jnp.uint32,
+                         None, "model", None)},
+        pol, spec((batch, cfg.n_features), jnp.uint8, None, None),
+        spec((batch,), jnp.int32, None), spec((2,), jnp.uint32, None),
+        spec((batch,), jnp.bool_, None), spec((), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert "f32[20000,40000]" not in hlo
+    assert "f32[5000,40000]" in hlo                 # the shard's own rows
+    gathers = re.findall(r' gather\(.*op_name="([^"]*)"', hlo)
+    assert not [g for g in gathers if scopes.DRAWS in g.split("/")]
+    votes = [ln for ln in hlo.splitlines()
+             if re.search(r"\ball-reduce(-start)?\(", ln)
+             and "/while/body/" in ln]
+    assert len(votes) == 2 and all(scopes.VOTES in ln for ln in votes)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
