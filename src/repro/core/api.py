@@ -158,7 +158,12 @@ def replay_events(cfg: TMConfig, caches: dict, old_inc: jax.Array,
     """The tail every topology's step shares: diff the include masks into a
     counted event buffer (``tm.events``), then every cache absorbs the
     events through its provider (``tm.cache_sync``). Returns the new caches
-    and the buffer, whose ``overflow`` the caller accumulates."""
+    and the buffer, whose ``overflow`` the caller accumulates.
+
+    The packed words (``bitpack``) are repacked from ``new_state`` and read
+    no event, so they are exact even when the buffer overflows; in a
+    bundle with no other cache only the buffer's ``overflow`` is live, and
+    XLA drops the selection's cumsums, slot scatter and sort."""
     with jax.named_scope(scopes.EVENTS):
         buf = indexing.events_from_transition(
             old_inc, include_mask(cfg, new_state), max_events)

@@ -9,9 +9,13 @@ falsification index, Gorji et al. 2020); the Massively Parallel TM line
   * ``EvalEngine`` — ``prepare(cfg, state) -> cache`` builds the engine's
     pytree cache (packed include words, ``CompactClauses``, ``ClauseIndex``);
     ``scores(cfg, cache, x)`` evaluates from the cache alone;
-    ``update_cache(cfg, cache, state, events)`` absorbs include/exclude
-    boundary crossings *incrementally* so learning never rebuilds or
-    host-syncs a cache per step.
+    ``update_cache(cfg, cache, state, events)`` brings the cache to the
+    post-update state inside the jitted step, never through the host:
+    ``compact`` and ``indexed`` absorb the include/exclude boundary
+    crossings incrementally, while the packed words are repacked from the
+    new state (one O(cells) pass, no scatter or sort, exact even when the
+    event buffer overflows). A bundle whose only cache is the packed words
+    reads no event, so its event buffer is dead code that XLA removes.
   * ``register_engine`` / ``get_engine`` / ``registered_engines`` — the
     registry. ``dense``, ``bitpack``, ``bitpack_xla``, ``compact`` and
     ``indexed`` register at import; new engines (sharded, weighted, …)
@@ -44,7 +48,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.core import indexing, tm
-from repro.core.bitpack import WORD, pack_bits, packed_literals
+from repro.core.bitpack import pack_bits, packed_literals
 from repro.core.indexing import Event
 from repro.core.types import (
     TMConfig, TMState, clause_polarity, include_mask, literals_from_input)
@@ -74,7 +78,8 @@ class EvalEngine:
     state via ``shard_prepare``, and evaluates partial votes via
     ``partial_scores``. ``update_cache`` is *already* shard-local: Type I/II
     feedback is clause-local given the vote, so each shard replays only its
-    own events against its own cache — no extra methods needed for learning.
+    own events against its own cache (or, for the packed words, repacks its
+    own rows) — no extra methods needed for learning.
     """
 
     name: str = ""
@@ -91,12 +96,13 @@ class EvalEngine:
 
     def update_cache(self, cfg: TMConfig, cache, state: TMState,
                      events: Event):
-        """Absorb TA boundary crossings; default falls back to a rebuild.
+        """Absorb TA boundary crossings; default rebuilds from ``state``.
 
         ``state`` is the *post*-update TA state; ``events`` the include-mask
         diff that produced it (``indexing.events_from_transition``). Caches
         must have been in sync with the pre-update state — the TMBundle sync
-        contract (DESIGN.md §3).
+        contract (DESIGN.md §3). The rebuild reads no event, so it stays
+        exact when the buffer overflows.
         """
         del events
         return self.prepare(cfg, state)
@@ -211,22 +217,6 @@ class DenseEngine(EvalEngine):
 # ---------------------------------------------------------------------------
 
 
-def packed_include_apply_events(words: jax.Array, events: Event) -> jax.Array:
-    """Flip include bits for a masked event buffer, one scatter-add.
-
-    Events from ``events_from_transition`` touch *distinct* (i, j, k) cells
-    and always cross the boundary in the stated direction (insert: bit is 0,
-    delete: bit is 1), so per-word bit deltas sum without carries and the
-    whole buffer lands in a single vectorised scatter — no scan.
-    """
-    word = events.literal // WORD
-    bit = (events.literal % WORD).astype(jnp.uint32)
-    mask = (jnp.uint32(1) << bit).astype(jnp.uint32)
-    sign = jnp.where(events.is_insert, jnp.uint32(1), jnp.uint32(0xFFFFFFFF))
-    delta = jnp.where(events.valid, mask * sign, jnp.uint32(0))
-    return words.at[events.cls, events.clause, word].add(delta, mode="drop")
-
-
 class BitpackEngine(EvalEngine):
     """32×-packed include words, evaluated through the kernel backend
     registry (``kernels/backend.py``): the ``clause_votes`` primitive
@@ -238,6 +228,11 @@ class BitpackEngine(EvalEngine):
     ``bitpack_xla`` is a registry *alias*: the same engine pinned to
     ``backend='xla'`` regardless of the config (it shares the ``bitpack``
     cache slot, so a bundle maintains the packed words once).
+
+    The words are kept by the default ``update_cache``, a repack of the new
+    state: one pass over the TA cells with no scatter or sort, cheaper than
+    replaying a buffer sized for the step's worst case, and exact whatever
+    the buffer dropped.
     """
 
     cache_key = "bitpack"
@@ -254,10 +249,6 @@ class BitpackEngine(EvalEngine):
 
     def prepare(self, cfg: TMConfig, state: TMState) -> jax.Array:
         return pack_bits(include_mask(cfg, state).astype(jnp.uint8))
-
-    def update_cache(self, cfg, cache, state, events):
-        del state
-        return packed_include_apply_events(cache, events)
 
     def cache_pspec(self, cfg):
         return P(None, CLAUSE_AXIS, None)                     # (m, n, W)

@@ -162,6 +162,21 @@ def test_train_step_mask_ignores_padding_rows():
             f"parallel={parallel}: garbage rows had no effect unmasked"
 
 
+def test_bitpack_step_overflows_and_keeps_exact_words():
+    """A bitpack-only step with a 1-slot event buffer counts the dropped
+    crossings, and its packed words are still a fresh pack of the new
+    state."""
+    from repro.core.bitpack import pack_bits
+    from repro.core.types import include_mask
+    xs, ys = toy_data(16)
+    out = train_step_jit(init_bundle(CFG, engines=("bitpack",)), xs, ys,
+                         jax.random.key(0), max_events=1)
+    assert int(out.event_overflow) > 0
+    np.testing.assert_array_equal(
+        np.asarray(out.caches["bitpack"]),
+        np.asarray(pack_bits(include_mask(CFG, out.state))))
+
+
 # ---------------------------------------------------------------------------
 # TsetlinMachine estimator
 # ---------------------------------------------------------------------------

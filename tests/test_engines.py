@@ -13,7 +13,7 @@ from repro.core import (
     TMConfig, TMState, bundle_scores, get_engine, init_bundle,
     registered_engines, train_step_jit, validate,
 )
-from repro.core.engines import cache_provider, packed_include_apply_events
+from repro.core.engines import cache_provider
 from repro.core.indexing import events_from_transition
 from repro.core.types import include_mask
 
@@ -96,30 +96,35 @@ def test_engine_parity_after_jitted_training(parallel):
 # Incremental cache maintenance ≡ rebuild, per provider
 # ---------------------------------------------------------------------------
 
-def _transition_events(seed):
+def _transition_events(seed, max_events=ALL_EVENTS):
     s0 = random_state(CFG, seed)
     s1 = random_state(CFG, 50 + seed)
-    ev = events_from_transition(include_mask(CFG, s0),
-                                include_mask(CFG, s1), ALL_EVENTS).events
-    return s0, s1, ev
+    buf = events_from_transition(include_mask(CFG, s0),
+                                 include_mask(CFG, s1), max_events)
+    return s0, s1, buf
 
 
+@pytest.mark.parametrize("max_events", [ALL_EVENTS, 1],
+                         ids=["every_event", "overflowing"])
 @pytest.mark.parametrize("seed", range(3))
-def test_packed_cache_events_equal_repack(seed):
-    s0, s1, ev = _transition_events(seed)
+def test_bitpack_update_cache_is_fresh_pack(seed, max_events):
+    """The packed words after a step are a fresh pack of the new state,
+    also when the event buffer drops crossings."""
+    s0, s1, buf = _transition_events(seed, max_events)
+    assert (int(buf.overflow) > 0) == (max_events == 1)
     prov = cache_provider("bitpack")
-    got = packed_include_apply_events(prov.prepare(CFG, s0), ev)
-    want = prov.prepare(CFG, s1)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    got = prov.update_cache(CFG, prov.prepare(CFG, s0), s1, buf.events)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(prov.prepare(CFG, s1)))
 
 
 @pytest.mark.parametrize("key", ["dense", "bitpack", "compact", "indexed"])
 def test_update_cache_matches_prepare_scores(key):
     """Provider-level contract: update_cache(prepare(s0), events) scores
     identically to prepare(s1), for every distinct cache slot."""
-    s0, s1, ev = _transition_events(11)
+    s0, s1, buf = _transition_events(11)
     prov = cache_provider(key)
-    synced = prov.update_cache(CFG, prov.prepare(CFG, s0), s1, ev)
+    synced = prov.update_cache(CFG, prov.prepare(CFG, s0), s1, buf.events)
     xs = random_inputs(CFG, 1234, batch=5)
     eng = get_engine(key)  # cache_key == a registered engine name here
     np.testing.assert_array_equal(

@@ -10,7 +10,10 @@ modes, and both shard-local bodies (sync and stale-vote) of the sharded
 step in both modes, on a forced 4-device host mesh (data=2 × model=2, so
 the sequential step composes data × clause). Every all-reduce that the
 per-round vote psum lowers to carries ``tm.votes``; the stale-vote and
-single-device bodies have no vote psum and carry no ``tm.votes``.
+single-device bodies have no vote psum and carry no ``tm.votes``. A step
+whose only cache is the packed words, single-device or clause-sharded,
+compiles no sort or scatter under ``tm.events`` or ``tm.cache_sync``: the
+words are repacked, so the event buffer is dead code.
 """
 import json
 import os
@@ -68,6 +71,39 @@ def test_single_device_step_carries_every_scope(parallel):
     assert not all_reduces(hlo)
 
 
+def event_path_ops(hlo: str) -> list[str]:
+    """The ``op_name`` of every sort or scatter instruction, fused or not,
+    under ``tm.events`` or ``tm.cache_sync``."""
+    names = re.findall(r' (?:scatter|sort)\(.*op_name="([^"]*)"', hlo)
+    return [n for n in names
+            if {scopes.EVENTS, scopes.CACHE_SYNC} & set(scopes_in([n]))]
+
+
+@pytest.mark.parametrize("engines,selects", [
+    (("bitpack",), False), (("bitpack", "indexed"), True)],
+    ids=["bitpack", "bitpack_indexed"])
+def test_event_buffer_compiles_only_for_an_event_cache(engines, selects):
+    """The packed words are repacked from the new state, so a bitpack-only
+    step reads no event: its buffer's sort and scatters compile away, and
+    only its overflow count stays. An index in the bundle keeps them."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import TMConfig, init_bundle, train_step
+
+    cfg = TMConfig(n_classes=3, n_clauses=8, n_features=6, n_states=20,
+                   s=3.0, threshold=4)
+    bundle = init_bundle(cfg, engines=engines, rng=jax.random.key(0))
+    xs = jnp.zeros((4, 6), jnp.uint8)
+    ys = jnp.zeros((4,), jnp.int32)
+    step = jax.jit(train_step, static_argnames=("parallel", "max_events"))
+    hlo = step.lower(bundle, xs, ys, jax.random.key(1), None,
+                     max_events=cfg.n_classes * cfg.n_clauses
+                     * cfg.n_literals).compile().as_text()
+    assert bool(event_path_ops(hlo)) == selects
+    assert scopes_in(op_names(hlo)) == list(SCOPES)
+
+
 def all_reduces(hlo: str) -> list[str]:
     """The ``op_name`` of every all-reduce instruction of a compiled
     module ("" where it has none)."""
@@ -116,6 +152,13 @@ SHARDED = textwrap.dedent("""
                     + ("_async" if k else "_sync"))
             out[name] = sorted(set(re.findall(r'op_name="([^"]*)"', hlo)))
             out[name + "_hlo"] = hlo
+    # the clause-sharded bitpack-only step, as the four-chip benchmark runs
+    step = make_sharded_train_step(cfg, mesh, engines=("bitpack",),
+                                   max_events=32)
+    b = make_sharded_prepare(cfg, mesh, engines=("bitpack",))(state)
+    out["sequential_sync_bitpack_hlo"] = step.jitted.lower(
+        b.state, b.caches, step.pol, xs, ys, key, mask,
+        jnp.zeros((), jnp.int32)).compile().as_text()
     print("OPNAMES " + json.dumps(out))
 """)
 
@@ -155,3 +198,13 @@ def test_vote_psum_all_reduces_carry_the_votes_scope(sharded_op_names, body):
 @pytest.mark.parametrize("body", ["sequential_async", "parallel_async"])
 def test_stale_vote_bodies_carry_no_votes_scope(sharded_op_names, body):
     assert not any(scopes.VOTES in n for n in sharded_op_names[body])
+
+
+@pytest.mark.parametrize("body,selects", [
+    ("sequential_sync_bitpack", False), ("sequential_sync", True)],
+    ids=["bitpack", "bitpack_indexed"])
+def test_sharded_event_buffer_compiles_only_for_an_event_cache(
+        sharded_op_names, body, selects):
+    hlo = sharded_op_names[body + "_hlo"]
+    assert bool(event_path_ops(hlo)) == selects
+    assert scopes_in(op_names(hlo)) == list(SCOPES)

@@ -21,7 +21,8 @@ Registry-driven (subprocess, ``--xla_force_host_platform_device_count=8``):
 On a forced 4-device mesh (subprocess): ``Topology(clause_shards=4)``,
 whose shards draw only their own rows of the uniform stream, trains
 bit-exactly with ``Topology(1)`` and with the benchmark's plain reference
-(``bench/ref.py``), on an even and a ragged clause count.
+(``bench/ref.py``), on an even and a ragged clause count; with an event
+buffer that overflows, its packed words still equal a fresh pack.
 """
 import json
 import os
@@ -231,6 +232,8 @@ PER_SHARD = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from repro.core import TMConfig, TMState, Topology, TsetlinMachine
+    from repro.core.bitpack import pack_bits
+    from repro.core.types import include_mask
     from bench import ref
 
     out = {}
@@ -259,7 +262,17 @@ PER_SHARD = textwrap.dedent("""
                 got.append(np.asarray(machine.state.ta_state))
             states[name] = got
         want = [np.asarray(a) for a in ref.train_steps(ta0, batches, keys, tm)]
+        # a 1-slot buffer per shard overflows; the words are repacked
+        tight = TsetlinMachine(cfg, topology=Topology(clause_shards=4),
+                               engines=("bitpack",), max_events_per_batch=1)
+        tight.bundle = tight.session.prepare(TMState(ta_state=ta0))
+        tight.partial_fit(*batches[0], rng=keys[0])
+        words = pack_bits(include_mask(cfg, tight.bundle.state))
         out[n_clauses] = {
+            "tight_overflow": tight.event_overflow,
+            "tight_words_differ": int(
+                (np.asarray(tight.bundle.caches["bitpack"])
+                 != np.asarray(words)).sum()),
             "clause4_vs_one": [int((a != b).sum()) for a, b in
                                zip(states["clause4"], states["one"])],
             "clause4_vs_ref": [int((a != b).sum()) for a, b in
@@ -291,3 +304,14 @@ def test_clause4_training_equals_one_device_and_the_reference(
     assert run["clause4_vs_one"] == [0, 0, 0]
     assert run["clause4_vs_ref"] == [0, 0, 0]
     assert all(c > 0 for c in run["changed"])      # the steps learnt
+
+
+@pytest.mark.parametrize("n_clauses", ["16", "18"], ids=["even", "ragged"])
+def test_clause4_overflowing_step_keeps_exact_words(per_shard_runs,
+                                                     n_clauses):
+    """Each shard repacks its own rows: with a 1-slot event buffer the step
+    counts dropped crossings, and the packed words equal a fresh pack of
+    the new sharded state."""
+    run = per_shard_runs[n_clauses]
+    assert run["tight_overflow"] > 0
+    assert run["tight_words_differ"] == 0

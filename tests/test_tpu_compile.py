@@ -148,6 +148,16 @@ def test_event_selection_has_no_gather_for_v5e(v5e_train_step_hlo):
                 if scopes.EVENTS in name.split("/")]
 
 
+def test_bitpack_step_selects_no_events_for_v5e(v5e_train_step_hlo):
+    """The packed words are repacked from the new state, so the chip's
+    program of a bitpack-only step has no sort or scatter, fused or not,
+    under ``tm.events`` or ``tm.cache_sync``: the event buffer is gone."""
+    names = re.findall(r' (?:scatter|sort)\(.*op_name="([^"]*)"',
+                       v5e_train_step_hlo)
+    assert not [name for name in names
+                if {scopes.EVENTS, scopes.CACHE_SYNC} & set(name.split("/"))]
+
+
 def test_clause4_step_draws_only_its_own_rows_for_v5e(v5e, monkeypatch):
     """The sequential train step of I4 at 20,000 clauses, sharded by clause
     over the four chips, compiles for the chip: no op holds the full
