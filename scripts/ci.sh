@@ -1,6 +1,6 @@
 #!/usr/bin/env bash
 # CI smoke: tier-1 tests + the quickstart example on the estimator API +
-# one scaled-down benchmark cell + the TM serving smoke. Run from anywhere:
+# the TM serving smoke + the sharded-lowering dry run. Run from anywhere:
 #
 #     bash scripts/ci.sh
 #
@@ -39,9 +39,6 @@ EOF
 echo "== quickstart (TsetlinMachine estimator API) =="
 python examples/quickstart.py
 
-echo "== benchmark smoke cell =="
-python -m benchmarks.run --smoke
-
 echo "== tm_serve smoke (async serving runtime, sharded Pallas-interpret, 4-device mesh) =="
 rm -f BENCH_tm_serve.json
 XLA_FLAGS=--xla_force_host_platform_device_count=4 \
@@ -60,7 +57,7 @@ assert d["topology"]["backend"] == "pallas_interpret", d["topology"]
 # §9: the fired composition rule is part of the topology metadata
 assert d["topology"]["composition"] in (
     "composed_even", "composed_ragged", "clause_only"), d["topology"]
-# §11 satellite: the per-shard row census (where ragged padding lands)
+# §9: the per-shard row census (where ragged padding lands)
 rows = d["topology"]["shard_rows"]
 assert len(rows) == d["topology"]["clause_shards"], rows
 assert all({"shard", "real_rows", "pad_rows"} <= set(r) for r in rows), rows
@@ -98,8 +95,8 @@ print("BENCH_tm_serve.json well-formed:", ", ".join(d["engines"]),
                              for n, r in sl["engines"].items()})
 EOF
 
-echo "== dryrun --tm --async-votes (backend routes + vote all-reduce + async stale-vote path) =="
-python -m repro.launch.dryrun --tm --async-votes
+echo "== dryrun --tm (backend routes + vote all-reduce) =="
+python -m repro.launch.dryrun --tm
 python - <<'EOF'
 import json
 # even cell (PR 3/4 contract) + the previously-indivisible ragged cell (§9)
@@ -115,7 +112,7 @@ for mesh, rule in (("2x4", "composed_even"), ("2x3", "composed_ragged")):
     # the indexed engine's matmul-form Eq. 4 routes the same way
     # (indexed_votes primitive: pallas_call ⇔ pallas backend, the one vote
     # all-reduce unchanged), and its train leg covers index_update — the
-    # batched replay keeps the step all-reduce-only on both backends (§12)
+    # batched replay keeps the step all-reduce-only on both backends (§11)
     ipi = routes["indexed_pallas_interpret"]
     assert ipi["pallas_call_in_jaxpr"] and ipi["one_vote_all_reduce"], ipi
     assert ipi["train_step_all_reduce_only"], ipi
@@ -127,77 +124,6 @@ for mesh, rule in (("2x4", "composed_even"), ("2x3", "composed_ragged")):
     assert seq["composition"] == rule and seq["all_reduce_only"], seq
     print(f"dryrun --tm {mesh} OK: composition={seq['composition']},",
           {k: v["pallas_call_in_jaxpr"] for k, v in routes.items()})
-# §11: the async route record — zero vote collectives inside the step
-# (nothing at all on a clause-only mesh), exactly one batched all-reduce
-# per K-step refresh, and the sync-minus-async collective arithmetic
-a = json.load(open("results/dryrun/tm/async.json"))
-assert not a["failures"], a["failures"]
-assert set(a["cells"]) == {"1x4/sequential", "2x4/sequential",
-                           "2x4/parallel"}, sorted(a["cells"])
-for key, c in a["cells"].items():
-    assert c["zero_vote_collectives"], (key, c)
-    assert c["one_refresh_all_reduce"], (key, c)
-    assert c["removed_vote_collectives"], (key, c)
-assert a["cells"]["1x4/sequential"]["async_count"] == 0, a["cells"]
-print("dryrun --tm async OK:",
-      {k: f"sync={c['sync_count']} async={c['async_count']} "
-          f"refresh={c['refresh_count']}" for k, c in a["cells"].items()})
-EOF
-
-echo "== BENCH_tm.json backend sweep (engine x backend x topology) =="
-rm -f BENCH_tm.json
-XLA_FLAGS=--xla_force_host_platform_device_count=4 \
-  python -m benchmarks.tm_speedup --sweep-only
-python - <<'EOF'
-import json
-d = json.load(open("BENCH_tm.json"))
-assert d["schema"] == 4, f"expected schema 4, got {d.get('schema')}"
-sweep = d["backend_sweep"]
-assert sweep, "empty backend_sweep in BENCH_tm.json"
-cells = {(r["engine"], r["backend"], r["clause_shards"], r["data_shards"])
-         for r in sweep}
-for engine in ("bitpack", "indexed"):
-    for backend in ("xla", "pallas_interpret"):
-        for shards in (1, 4):
-            assert (engine, backend, shards, 1) in cells, (
-                engine, backend, shards, sorted(cells))
-        # §9: the ragged 2×2 data×clause cell rides along per backend
-        assert (engine, backend, 2, 2) in cells, (
-            engine, backend, sorted(cells))
-ragged = [r for r in sweep if r["composition"] == "composed_ragged"]
-assert ragged, [r["composition"] for r in sweep]
-for r in sweep:
-    assert r["infer_us"] > 0 and r["train_us"] > 0, r
-    assert r["devices"] == 4, r
-# §11: the sync-vs-async sweep — every K × shards cell present with a
-# positive step time and its accuracy recorded next to the K=0 baseline;
-# the removed vote collectives must show up as a step-time win for at
-# least one K>0 cell on this forced-4-device host
-sva = d["train_sync_vs_async"]
-assert sva, "empty train_sync_vs_async in BENCH_tm.json"
-cells = {(r["k"], r["clause_shards"]) for r in sva}
-assert cells == {(k, s) for k in (0, 1, 4, 16) for s in (2, 4)}, cells
-for r in sva:
-    assert r["step_us"] > 0 and r["devices"] == 4, r
-    assert 0.0 <= r["accuracy"] <= 1.0, r
-    assert {"accuracy_sync", "accuracy_delta", "speedup_vs_sync",
-            "composition"} <= set(r), r
-best = max(r["speedup_vs_sync"] for r in sva if r["k"] > 0)
-assert best > 1.0, f"async never beat sync: best speedup {best:.3f}"
-# §12: the indexed-vs-dense speedup curve (schema 4) — work_ratio present
-# on every cell, and at the paper-like sparse high-clause cell the
-# matmul-form indexed engine must strictly beat dense on the full batch
-curve = d["indexed_speedup"]
-assert curve, "empty indexed_speedup in BENCH_tm.json"
-for r in curve:
-    assert r["work_ratio"] > 0, r
-    assert r["infer_dense_us"] > 0 and r["infer_indexed_us"] > 0, r
-sparse = min(curve, key=lambda r: (-r["n_clauses"], r["avg_clause_len"]))
-assert sparse["infer_indexed_us"] < sparse["infer_dense_us"], sparse
-print(f"BENCH_tm.json backend sweep well-formed: {len(sweep)} cells "
-      f"({len(ragged)} composed_ragged); sync_vs_async {len(sva)} rows, "
-      f"best async speedup {best:.2f}x; indexed_speedup {len(curve)} cells, "
-      f"sparse high-clause cell {sparse['speedup']:.2f}x")
 EOF
 
 echo "CI smoke: OK"

@@ -10,11 +10,7 @@ persists **state + config fingerprint only**:
     caches on the *restoring* topology via ``TMSession.prepare`` — the same
     reshard-on-restore machinery the fault-tolerant trainer uses — so a
     checkpoint written under ``Topology(clause_shards=4)`` loads bit-exactly
-    under any other placement. The async stale-vote accumulator
-    (``TMBundle.vote_acc``, DESIGN.md §11) is the same kind of rebuildable
-    state: it is never persisted — restore under ``async_votes=K`` seeds a
-    fresh zero ``VoteAccumulator`` on the restoring topology, and the
-    cold-start staleness transient decays within one refresh window;
+    under any other placement;
   * the config fingerprint (sha256 over the canonical ``TMConfig`` field
     dump) catches restoring into a machine whose semantics differ — shapes
     alone cannot (e.g. a changed ``s`` or ``threshold`` keeps every shape).

@@ -45,31 +45,23 @@ class TMBundle:
     buffer is per clause shard, so the threshold the counter reflects scales
     with ``clause_shards`` — size ``max_events`` for the least-sharded
     placement a state will run on.
-
-    ``vote_acc`` is the double-buffered stale-vote accumulator
-    (``types.VoteAccumulator``) carried only when a sharded topology trains
-    with ``async_votes=K>0`` (DESIGN.md §11); None everywhere else. It is
-    rebuildable state — checkpoints never persist it.
     """
 
     cfg: TMConfig
     state: TMState
     caches: dict[str, Any]
     event_overflow: jax.Array | None = None
-    vote_acc: Any = None
 
     def tree_flatten(self):
-        """Pytree protocol: leaves = (state, caches, overflow, vote_acc),
-        aux = cfg."""
-        return ((self.state, self.caches, self.event_overflow, self.vote_acc),
-                self.cfg)
+        """Pytree protocol: leaves = (state, caches, overflow), aux = cfg."""
+        return ((self.state, self.caches, self.event_overflow), self.cfg)
 
     @classmethod
     def tree_unflatten(cls, cfg, children):
         """Pytree protocol: rebuild from ``tree_flatten``'s output."""
-        state, caches, event_overflow, vote_acc = children
+        state, caches, event_overflow = children
         return cls(cfg=cfg, state=state, caches=caches,
-                   event_overflow=event_overflow, vote_acc=vote_acc)
+                   event_overflow=event_overflow)
 
     @property
     def index(self) -> indexing.ClauseIndex:
@@ -218,7 +210,7 @@ def train_step(
     if bundle.event_overflow is not None:
         overflow = overflow + bundle.event_overflow
     return TMBundle(cfg=cfg, state=new_state, caches=caches,
-                    event_overflow=overflow, vote_acc=bundle.vote_acc)
+                    event_overflow=overflow)
 
 
 # Donation updates TA states/caches in place on accelerators; the CPU backend

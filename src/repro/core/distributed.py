@@ -53,7 +53,7 @@ clauses (local ids, dense under padding), which is what makes the
 falsified-union shard-local and the partial votes additive — and since the
 shard's position-matrix slice carries the same membership information
 (``pos != NA`` ⇔ local include), the matmul-form Eq. 4 body
-(``indexed_votes``, DESIGN.md §12) evaluates the shard's partial votes
+(``indexed_votes``, DESIGN.md §11) evaluates the shard's partial votes
 with no list walk at all; batched index maintenance (``index_update``)
 replays each shard's own event buffer shard-locally, exactly like the
 scan it replaced.
@@ -73,7 +73,7 @@ from repro.core.api import (
     DEFAULT_ENGINE, TMBundle, cache_keys_for, replay_events, resolve_donate)
 from repro.core.engines import CLAUSE_AXIS, cache_provider, get_engine
 from repro.core.types import (
-    TMConfig, TMState, VoteAccumulator, clause_polarity, include_mask)
+    TMConfig, TMState, clause_polarity, include_mask)
 
 STATE_PSPEC = TMState(ta_state=P(None, CLAUSE_AXIS, None))
 
@@ -239,53 +239,12 @@ def _sharded_polarity(cfg: TMConfig, mesh) -> jax.Array:
     return jax.device_put(pol, NamedSharding(mesh, P(CLAUSE_AXIS)))
 
 
-def vote_acc_pspec(mesh) -> VoteAccumulator:
-    """``VoteAccumulator`` PartitionSpecs: one row per (data × clause) rank.
-
-    The row axis shards jointly over every batch axis and the clause axis
-    (pod-major, clause-minor — matching the mesh's P ordering), so each
-    mesh position owns exactly one ``(1, m)`` local/stale block and one
-    overflow scalar inside shard_map.
-    """
-    row = (*batch_axes(mesh), CLAUSE_AXIS)
-    return VoteAccumulator(local=P(row, None), stale=P(row, None),
-                           overflow=P(row))
-
-
-def vote_ranks(mesh) -> int:
-    """R — total vote ranks (product of batch-axis sizes × clause shards)."""
-    baxes = batch_axes(mesh)
-    d = math.prod(mesh.shape[a] for a in baxes) if baxes else 1
-    return d * clause_shards(mesh)
-
-
-def init_vote_acc(cfg: TMConfig, mesh) -> VoteAccumulator:
-    """Fresh all-zeros accumulator, placed per ``vote_acc_pspec``.
-
-    Zeros are the correct cold start: a zero stale term makes the first
-    window read pure local votes, and the first refresh replaces it with
-    real sums. Explicit per-field device_put (PartitionSpec is a tuple
-    subclass — tree-mapping over a spec tree would descend into it).
-    """
-    r, m = vote_ranks(mesh), cfg.n_classes
-    spec = vote_acc_pspec(mesh)
-    put = lambda arr, s: jax.device_put(arr, NamedSharding(mesh, s))  # noqa: E731
-    return VoteAccumulator(
-        local=put(jnp.zeros((r, m), jnp.int32), spec.local),
-        stale=put(jnp.zeros((r, m), jnp.int32), spec.stale),
-        overflow=put(jnp.zeros((r,), jnp.int32), spec.overflow))
-
-
-def make_sharded_prepare(cfg: TMConfig, mesh, *, engines=None,
-                         async_votes: int = 0):
+def make_sharded_prepare(cfg: TMConfig, mesh, *, engines=None):
     """``(TMState) -> TMBundle`` with shard-local caches for every engine.
 
     The state pads to the ragged clause layout and lands clause-sharded
     (``STATE_PSPEC``); each distinct cache slot is built *on its shard*
     from the local state slice — no device ever materialises a full cache.
-    ``async_votes > 0`` additionally seeds the bundle's stale-vote
-    accumulator (``init_vote_acc`` zeros — rebuildable state, never
-    checkpointed).
     """
     geom = geometry(cfg, mesh)
     shards = geom.clause_shards
@@ -305,10 +264,8 @@ def make_sharded_prepare(cfg: TMConfig, mesh, *, engines=None,
         state = pad_state(cfg, state, geom.n_padded)
         state = TMState(ta_state=jax.device_put(state.ta_state, state_sh))
         caches = fn(state) if keys else {}
-        acc = init_vote_acc(cfg, mesh) if async_votes > 0 else None
         return TMBundle(cfg=cfg, state=state, caches=caches,
-                        event_overflow=jnp.zeros((), jnp.int32),
-                        vote_acc=acc)
+                        event_overflow=jnp.zeros((), jnp.int32))
 
     return prepare
 
@@ -382,8 +339,7 @@ def make_sharded_scores(cfg: TMConfig, mesh, *, engine: str = DEFAULT_ENGINE):
 
 def make_sharded_train_step(cfg: TMConfig, mesh, *, engines=None,
                             parallel: bool = False, max_events: int = 4096,
-                            donate: bool | None = None,
-                            async_votes: int = 0):
+                            donate: bool | None = None):
     """``(TMBundle, xs, ys, rng[, mask]) -> TMBundle``, sharded end to end.
 
     Sequential mode keeps the paper's global sample order (online learning
@@ -413,17 +369,6 @@ def make_sharded_train_step(cfg: TMConfig, mesh, *, engines=None,
     contract of ``api.train_step``); omitted → all rows valid. The fired
     composition rule is exposed as ``step.composition`` (and recorded by
     ``dryrun --tm`` / BENCH_tm_serve.json).
-
-    ``async_votes > 0`` compiles the *asynchronous* step (DESIGN.md §11):
-    every class round reads ``live local votes + bundle.vote_acc.stale``
-    instead of psumming, so the step body contains **zero vote
-    collectives** and no per-step overflow psum either (per-rank drop
-    counts accumulate into the accumulator and ride the K-step refresh,
-    ``make_vote_refresh``). The only collectives left are the ones state
-    exactness genuinely requires: the reassembly psum under hierarchical
-    composition, or the delta psum in batch-parallel mode — clause-only
-    async training is collective-free. The step never refreshes the
-    buffer itself; the session owns the K cadence.
     """
     geom = geometry(cfg, mesh)
     n_local = geom.n_local
@@ -451,14 +396,11 @@ def make_sharded_train_step(cfg: TMConfig, mesh, *, engines=None,
     pol = _sharded_polarity(cfg, mesh)
 
     def shard_step(state_l: TMState, caches_l, pol_l, xs, ys, key_data,
-                   mask, stale):
-        # The shard-local step both bodies below share, under the step's
-        # scope names (core/scopes.py): the shard's Type I/II update, then
-        # the events-and-sync tail of api.train_step. ``stale`` (m,) — the
-        # accumulator's read buffer — switches every round to stale-vote
-        # feedback (DESIGN.md §11; the rounds then ignore ``axis_name``
-        # and return per-class vote stats). Returns
-        # (new_state, new_caches, EventBuffer, vote stats or None).
+                   mask):
+        # The shard-local step under the step's scope names
+        # (core/scopes.py): the shard's Type I/II update, then the
+        # events-and-sync tail of api.train_step. Returns
+        # (new_state, new_caches, EventBuffer).
         rng = jax.random.wrap_key_data(key_data)
         start = jax.lax.axis_index(CLAUSE_AXIS) * n_local
         with jax.named_scope(scopes.EVENTS):
@@ -477,12 +419,11 @@ def make_sharded_train_step(cfg: TMConfig, mesh, *, engines=None,
                 b_total = (xs.shape[0]
                            * math.prod(mesh.shape[a] for a in baxes)
                            if baxes else None)
-                out = tm.update_batch_parallel(
+                new_state = tm.update_batch_parallel(
                     cfg, state_l, xs, ys, rng, pol=pol_l,
                     axis_name=CLAUSE_AXIS, clause_start=start,
                     batch_axes=baxes, batch_start=b_idx * xs.shape[0],
-                    batch_total=b_total, mask=mask, clause_mask=local_valid,
-                    stale_votes=stale)
+                    batch_total=b_total, mask=mask, clause_mask=local_valid)
             elif compose:
                 # this data rank owns clause rows [d·n_sub, (d+1)·n_sub) of
                 # the model shard's (sub-slice-padded) slice; votes psum
@@ -502,25 +443,22 @@ def make_sharded_train_step(cfg: TMConfig, mesh, *, engines=None,
                     rows = off + jnp.arange(n_sub)
                     sub_valid = ((rows < n_local)
                                  & ((start + rows) < cfg.n_clauses))
-                out = tm.update_batch_sequential(
+                new_state = tm.update_batch_sequential(
                     cfg, sub, xs, ys, rng, pol=pol_sub,
                     axis_name=(*all_baxes, CLAUSE_AXIS),
                     clause_start=start + off, mask=mask,
-                    clause_mask=sub_valid, stale_votes=stale)
+                    clause_mask=sub_valid)
             else:
-                out = tm.update_batch_sequential(
+                new_state = tm.update_batch_sequential(
                     cfg, state_l, xs, ys, rng, pol=pol_l,
                     axis_name=CLAUSE_AXIS, clause_start=start, mask=mask,
-                    clause_mask=local_valid, stale_votes=stale)
-            new_state, stats = out if stale is not None else (out, None)
+                    clause_mask=local_valid)
             if compose:
                 # reassemble the model shard's slice: each real row is owned
                 # by exactly one data rank, so a zero-padded psum is a
                 # gather expressed as the one collective kind this step
-                # allows (async too: state composition must be exact, only
-                # the vote feedback term may go stale); the trailing
-                # sub-slice padding rows land past n_local and are dropped
-                # by the slice
+                # allows; the trailing sub-slice padding rows land past
+                # n_local and are dropped by the slice
                 zeros = jnp.zeros(
                     (state_l.ta_state.shape[0], n_sub_pad,
                      state_l.ta_state.shape[2]), state_l.ta_state.dtype)
@@ -531,12 +469,12 @@ def make_sharded_train_step(cfg: TMConfig, mesh, *, engines=None,
                     summed, 0, n_local, axis=1))
         new_caches, buf = replay_events(cfg, caches_l, old_inc, new_state,
                                         max_events)
-        return new_state, new_caches, buf, stats
+        return new_state, new_caches, buf
 
     def local_fn(state_l: TMState, caches_l, pol_l, xs, ys, key_data, mask,
                  overflow_in):
-        new_state, new_caches, buf, _ = shard_step(
-            state_l, caches_l, pol_l, xs, ys, key_data, mask, None)
+        new_state, new_caches, buf = shard_step(
+            state_l, caches_l, pol_l, xs, ys, key_data, mask)
         # per-shard drop counts add over the clause axis (each model shard
         # diffs only its own include slice; data ranks see identical diffs),
         # yielding the replicated global overflow counter — an all-reduce,
@@ -544,139 +482,32 @@ def make_sharded_train_step(cfg: TMConfig, mesh, *, engines=None,
         overflow = overflow_in + jax.lax.psum(buf.overflow, CLAUSE_AXIS)
         return new_state, new_caches, overflow
 
-    def local_fn_async(state_l: TMState, caches_l, pol_l, acc_l, xs, ys,
-                       key_data, mask):
-        # Same shard-local step as local_fn, with the vote psum (and the
-        # per-step overflow psum) deleted: rounds read the accumulator's
-        # stale remote term, vote/overflow stats land in the write buffer.
-        new_state, new_caches, buf, (vs, vc) = shard_step(
-            state_l, caches_l, pol_l, xs, ys, key_data, mask,
-            acc_l.stale[0])
-        # write buffer: batch-mean local partial votes per touched class
-        # (untouched classes keep their previous estimate); overflow counts
-        # accumulate per rank and drain at the next refresh collective
-        new_local = jnp.where(
-            vc > 0,
-            jnp.round(vs / jnp.maximum(vc, 1)).astype(jnp.int32),
-            acc_l.local[0])
-        acc_out = VoteAccumulator(
-            local=new_local[None], stale=acc_l.stale,
-            overflow=acc_l.overflow + buf.overflow)
-        return new_state, new_caches, acc_out
-
     mask_spec = y_spec  # batch-sharded in parallel mode, replicated otherwise
-    if async_votes > 0:
-        acc_spec = vote_acc_pspec(mesh)
-        sm = jax.shard_map(
-            local_fn_async, mesh=mesh,
-            in_specs=(STATE_PSPEC, cache_specs, P(CLAUSE_AXIS), acc_spec,
-                      x_spec, y_spec, P(None), mask_spec),
-            out_specs=(STATE_PSPEC, cache_specs, acc_spec), check_vma=False)
-        donate_nums = (0, 1, 3) if resolve_donate(donate) else ()
-        fn = jax.jit(sm, donate_argnums=donate_nums)
+    sm = jax.shard_map(
+        local_fn, mesh=mesh,
+        in_specs=(STATE_PSPEC, cache_specs, P(CLAUSE_AXIS), x_spec,
+                  y_spec, P(None), mask_spec, P()),
+        out_specs=(STATE_PSPEC, cache_specs, P()), check_vma=False)
+    donate_nums = (0, 1) if resolve_donate(donate) else ()
+    fn = jax.jit(sm, donate_argnums=donate_nums)
 
-        def step(bundle: TMBundle, xs, ys, rng, mask=None) -> TMBundle:
-            if bundle.vote_acc is None:
-                raise ValueError(
-                    "async_votes > 0 needs a bundle carrying a "
-                    "VoteAccumulator — prepare it with "
-                    "make_sharded_prepare(..., async_votes=K) (or let "
-                    "TMSession.prepare do it)")
-            if mask is None:
-                mask = jnp.ones(xs.shape[0], bool)
-            new_state, new_caches, acc = fn(
-                bundle.state, bundle.caches, pol, bundle.vote_acc, xs, ys,
-                jax.random.key_data(rng), mask)
-            return TMBundle(cfg=cfg, state=new_state, caches=new_caches,
-                            event_overflow=bundle.event_overflow,
-                            vote_acc=acc)
-    else:
-        sm = jax.shard_map(
-            local_fn, mesh=mesh,
-            in_specs=(STATE_PSPEC, cache_specs, P(CLAUSE_AXIS), x_spec,
-                      y_spec, P(None), mask_spec, P()),
-            out_specs=(STATE_PSPEC, cache_specs, P()), check_vma=False)
-        donate_nums = (0, 1) if resolve_donate(donate) else ()
-        fn = jax.jit(sm, donate_argnums=donate_nums)
-
-        def step(bundle: TMBundle, xs, ys, rng, mask=None) -> TMBundle:
-            if mask is None:
-                mask = jnp.ones(xs.shape[0], bool)
-            overflow_in = (bundle.event_overflow
-                           if bundle.event_overflow is not None
-                           else jnp.zeros((), jnp.int32))
-            new_state, new_caches, overflow = fn(
-                bundle.state, bundle.caches, pol, xs, ys,
-                jax.random.key_data(rng), mask, overflow_in)
-            return TMBundle(cfg=cfg, state=new_state, caches=new_caches,
-                            event_overflow=overflow,
-                            vote_acc=bundle.vote_acc)
+    def step(bundle: TMBundle, xs, ys, rng, mask=None) -> TMBundle:
+        if mask is None:
+            mask = jnp.ones(xs.shape[0], bool)
+        overflow_in = (bundle.event_overflow
+                       if bundle.event_overflow is not None
+                       else jnp.zeros((), jnp.int32))
+        new_state, new_caches, overflow = fn(
+            bundle.state, bundle.caches, pol, xs, ys,
+            jax.random.key_data(rng), mask, overflow_in)
+        return TMBundle(cfg=cfg, state=new_state, caches=new_caches,
+                        event_overflow=overflow)
 
     # exposed for the dry-run's HLO assertions (launch/dryrun.py --tm)
     step.jitted, step.pol = fn, pol
     step.geometry = geom
     step.composition = "batch_parallel" if parallel else geom.composition
     return step
-
-
-def make_vote_refresh(cfg: TMConfig, mesh, *, parallel: bool = False,
-                      donate: bool | None = None):
-    """``(TMBundle) -> TMBundle`` — the K-step stale-vote refresh (§11).
-
-    One batched all-reduce: each rank's ``(m,)`` local votes and its
-    overflow scalar pack into a single ``(m+1,)`` psum. The vote axes match
-    the async step's partitioning — every mesh axis under hierarchical
-    composition (ranks own disjoint clause rows), the clause axis alone
-    otherwise (data ranks replicate clause rows, so their totals already
-    agree per rank) — and under composition only data-rank 0 contributes
-    overflow (the ranks record identical drop counts for a clause shard;
-    summing all of them would multiply-count by ``data_shards``).
-
-    Out the other side: ``stale`` holds ``global − own local`` (the remote
-    term the next window reads), per-rank overflow drains to zero, and the
-    bundle's ``event_overflow`` absorbs the window's global drop count —
-    the per-step overflow psum the sync path pays rides this collective
-    instead. Exposes ``refresh.jitted`` for the dry-run's HLO assertions.
-    """
-    geom = geometry(cfg, mesh)
-    all_baxes = batch_axes(mesh)
-    compose = (not parallel) and geom.composes
-    vote_axes = (*all_baxes, CLAUSE_AXIS) if compose else (CLAUSE_AXIS,)
-    m = cfg.n_classes
-    acc_spec = vote_acc_pspec(mesh)
-
-    def local_fn(acc_l, overflow_in):
-        local = acc_l.local[0]      # (m,)
-        oflow = acc_l.overflow[0]   # ()
-        if compose and all_baxes:
-            d_idx = jnp.int32(0)
-            for a in all_baxes:
-                d_idx = d_idx * mesh.shape[a] + jax.lax.axis_index(a)
-            oflow = jnp.where(d_idx == 0, oflow, 0)
-        packed = jnp.concatenate([local, oflow[None].astype(jnp.int32)])
-        total = jax.lax.psum(packed, vote_axes)  # THE one all-reduce per K
-        stale = total[:m] - local
-        acc_out = VoteAccumulator(
-            local=acc_l.local, stale=stale[None],
-            overflow=jnp.zeros_like(acc_l.overflow))
-        return acc_out, overflow_in + total[m]
-
-    sm = jax.shard_map(local_fn, mesh=mesh, in_specs=(acc_spec, P()),
-                       out_specs=(acc_spec, P()), check_vma=False)
-    fn = jax.jit(sm, donate_argnums=(0,) if resolve_donate(donate) else ())
-
-    def refresh(bundle: TMBundle) -> TMBundle:
-        if bundle.vote_acc is None:
-            raise ValueError("refresh needs a bundle with a VoteAccumulator")
-        overflow_in = (bundle.event_overflow
-                       if bundle.event_overflow is not None
-                       else jnp.zeros((), jnp.int32))
-        acc, overflow = fn(bundle.vote_acc, overflow_in)
-        return TMBundle(cfg=cfg, state=bundle.state, caches=bundle.caches,
-                        event_overflow=overflow, vote_acc=acc)
-
-    refresh.jitted = fn
-    return refresh
 
 
 # The stateful facade over these factories is ``core/session.py``'s

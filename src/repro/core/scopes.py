@@ -1,10 +1,10 @@
 """Names of the train step's phases (``jax.named_scope``).
 
-Every topology's step — ``api.train_step`` (sequential or batch-parallel)
-and both shard-local bodies of ``distributed.make_sharded_train_step`` —
-carries the same four scopes; the sharded sync bodies add a fifth,
-``VOTES``, around the vote psum of each class round (the single-device
-and stale-vote bodies have no psum to name). They are metadata only: the
+Every topology's step — ``api.train_step`` and the shard-local body of
+``distributed.make_sharded_train_step``, sequential or batch-parallel —
+carries the same four scopes; the sharded body adds a fifth, ``VOTES``,
+around the vote psum of each class round (the single-device step has no
+psum to name). They are metadata only: the
 compiled ops are the same with or without them. The scope path reaches
 each op's ``op_name`` in the compiled program, so a device profile can
 attribute an op's time to a phase by its instruction name rather than by

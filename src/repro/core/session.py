@@ -101,13 +101,6 @@ class Topology:
                          ``'pallas'`` | ``'pallas_interpret'``; None defers
                          to ``TMConfig.backend``. Placement and kernel
                          choice are declared in one spot and resolved once.
-    ``async_votes``    — K > 0 trains clause shards *asynchronously* against
-                         a K-step-stale vote sum (DESIGN.md §11): no vote
-                         collective inside the step, one batched all-reduce
-                         per K steps refreshes the ``VoteAccumulator``.
-                         0 (default) keeps the bit-exact synchronous
-                         semantics. An execution knob like ``backend``:
-                         checkpoints ignore it.
     """
 
     clause_shards: int = 1
@@ -115,17 +108,12 @@ class Topology:
     engines: tuple[str, ...] | None = None
     donate: bool | None = None
     backend: str | None = None
-    async_votes: int = 0
 
     def __post_init__(self):
         if self.clause_shards < 1 or self.data_shards < 1:
             raise ValueError(
                 f"Topology shard counts must be >= 1, got clause_shards="
                 f"{self.clause_shards}, data_shards={self.data_shards}")
-        if self.async_votes < 0:
-            raise ValueError(
-                f"async_votes must be >= 0 (0 = synchronous), got "
-                f"{self.async_votes}")
         if self.engines is not None and not isinstance(self.engines, tuple):
             object.__setattr__(self, "engines", tuple(self.engines))
         if self.backend is not None:
@@ -149,8 +137,7 @@ class Topology:
         """Machine-readable placement summary (benchmarks record this)."""
         return {"clause_shards": self.clause_shards,
                 "data_shards": self.data_shards,
-                "devices": self.n_devices,
-                "async_votes": self.async_votes}
+                "devices": self.n_devices}
 
 
 def _topology_of_mesh(mesh, engines, donate) -> Topology:
@@ -192,8 +179,7 @@ class TMSession:
         if mesh is not None:
             adopted = _topology_of_mesh(mesh, topology.engines,
                                         topology.donate)
-            topology = dataclasses.replace(adopted, backend=topology.backend,
-                                           async_votes=topology.async_votes)
+            topology = dataclasses.replace(adopted, backend=topology.backend)
         if topology.backend is not None and topology.backend != cfg.backend:
             # the topology's kernel choice wins: everything downstream —
             # engines, the training round, the shard_map factories — reads
@@ -206,16 +192,8 @@ class TMSession:
         self.engines = (topology.engines if topology.engines is not None
                         else registered_engines())
         self._scores_fns: dict[str, object] = {}
-        self._refresh = None
-        self._pending_steps = 0  # steps since the last stale-vote refresh
 
         if not topology.is_sharded:
-            if topology.async_votes > 0:
-                raise ValueError(
-                    f"Topology(async_votes={topology.async_votes}) needs a "
-                    "sharded placement — on a single device there is no "
-                    "vote collective to make asynchronous; use "
-                    "clause_shards/data_shards > 1 (or async_votes=0)")
             self.mesh = None
             self.geometry = None
             self._prepare = None
@@ -239,15 +217,10 @@ class TMSession:
         # make_sharded_train_step warns when the rule is 'replicated'
         self.geometry = distributed.geometry(cfg, mesh)
         self._prepare = distributed.make_sharded_prepare(
-            cfg, mesh, engines=self.engines,
-            async_votes=topology.async_votes)
+            cfg, mesh, engines=self.engines)
         self._step = distributed.make_sharded_train_step(
             cfg, mesh, engines=self.engines, parallel=parallel,
-            max_events=max_events, donate=topology.donate,
-            async_votes=topology.async_votes)
-        if topology.async_votes > 0:
-            self._refresh = distributed.make_vote_refresh(
-                cfg, mesh, parallel=parallel, donate=topology.donate)
+            max_events=max_events, donate=topology.donate)
 
     # -- placement ----------------------------------------------------------
 
@@ -328,12 +301,6 @@ class TMSession:
         """One learning step (all maintained caches stay in sync). The
         input bundle is donated when the topology says so — do not read it
         afterwards.
-
-        Under ``async_votes=K`` the step itself performs no vote
-        collective; the session counts steps and chains the stale-vote
-        refresh (one batched all-reduce) onto every K-th step — the
-        cadence is host-side state, so the step executable stays
-        collective-clean for the dry-run's HLO assertions.
         """
         if self._step is not None:
             d = self.topology.data_shards
@@ -342,32 +309,11 @@ class TMSession:
                     f"batch size {xs.shape[0]} does not divide over "
                     f"data_shards={d} (batch-parallel learning shards the "
                     "batch); pick a divisible batch_size")
-            bundle = self._step(bundle, xs, ys, rng, mask)
-            if self._refresh is not None:
-                self._pending_steps += 1
-                if self._pending_steps >= self.topology.async_votes:
-                    bundle = self._refresh(bundle)
-                    self._pending_steps = 0
-            return bundle
+            return self._step(bundle, xs, ys, rng, mask)
         return train_step_jit(bundle, xs, ys, rng, mask,
                               parallel=self.parallel,
                               max_events=self.max_events,
                               donate=self.topology.donate)
-
-    def refresh_votes(self, bundle: TMBundle) -> TMBundle:
-        """Force a stale-vote refresh now (resets the K-step cadence).
-
-        No-op outside async mode. Useful before an accuracy read or a
-        checkpoint when mid-window staleness matters; also drains the
-        accumulated per-rank overflow counts into ``bundle.event_overflow``
-        (between refreshes the bundle's counter deliberately lags —
-        overflow accounting rides the refresh collective, never a per-step
-        psum).
-        """
-        if self._refresh is None:
-            return bundle
-        self._pending_steps = 0
-        return self._refresh(bundle)
 
     def _sharded_scores_fn(self, engine: str):
         """Memoised ``make_sharded_scores`` wrapper for one engine."""

@@ -206,7 +206,6 @@ def _class_round(
     # data axes (hierarchical data×clause sharding)
     axis_name: str | tuple[str, ...] | None = None,
     clause_mask: jax.Array | None = None,  # (n,) bool — False rows frozen
-    stale_vote: jax.Array | None = None,   # scalar — remote votes, K-step old
 ) -> jax.Array:
     """One feedback round for one class; returns updated (n, 2o) states.
 
@@ -215,16 +214,6 @@ def _class_round(
     per-class vote is the *only* cross-shard quantity (one psum — the vote
     all-reduce of the Massively Parallel TM architecture); Type I/II feedback
     is clause-local given that vote.
-
-    Asynchronous sharded learning (DESIGN.md §11) passes ``stale_vote``
-    instead of ``axis_name``: the round reads ``live local votes +
-    stale_vote`` — the remote shards' contribution from the last K-step
-    refresh — and performs **no collective at all**. The randomness-draw
-    discipline is untouched (draws happen in the caller either way), so a
-    sync and an async round consume identical keys; only the vote value the
-    feedback probability reads differs. In this mode the round additionally
-    returns its *local* partial vote sum, which the caller records into the
-    ``VoteAccumulator`` write buffer.
 
     ``clause_mask`` marks the rows that are *real* clauses: ragged shard
     slices (DESIGN.md §9) pad their clause axis, and a padding row must stay
@@ -247,14 +236,10 @@ def _class_round(
     if pol is None:
         pol = clause_polarity(cfg)
     t = float(cfg.threshold)
-    vote_local = jnp.sum(clause_out.astype(jnp.int32) * pol)
-    if stale_vote is not None:  # async: live local + K-step-stale remote
-        vote_sum = vote_local + stale_vote
-    else:
-        vote_sum = vote_local
-        if axis_name is not None:
-            with jax.named_scope(scopes.VOTES):
-                vote_sum = jax.lax.psum(vote_sum, axis_name)
+    vote_sum = jnp.sum(clause_out.astype(jnp.int32) * pol)
+    if axis_name is not None:
+        with jax.named_scope(scopes.VOTES):
+            vote_sum = jax.lax.psum(vote_sum, axis_name)
     votes = jnp.clip(vote_sum, -t, t)
     p = jnp.where(positive_round, (t - votes) / (2 * t), (t + votes) / (2 * t))
     active = rands.clause_gate < p                    # (n,)
@@ -270,10 +255,7 @@ def _class_round(
         ta_row.astype(jnp.int16), lit, clause_out, gets_type_i, active,
         rands.type_i, n_states=cfg.n_states, s=cfg.s,
         boost_true_positive=cfg.boost_true_positive)
-    new_row = new_row.astype(cfg.state_dtype)
-    if stale_vote is not None:
-        return new_row, vote_local
-    return new_row
+    return new_row.astype(cfg.state_dtype)
 
 
 def update_sample(
@@ -287,7 +269,6 @@ def update_sample(
     axis_name: str | tuple[str, ...] | None = None,
     clause_start: jax.Array | None = None,
     clause_mask: jax.Array | None = None,
-    stale_votes: jax.Array | None = None,
 ) -> TMState:
     """One online update (the paper's per-sample learning).
 
@@ -300,14 +281,6 @@ def update_sample(
     same uniform stream (``uniform_rows``), so the sharded update is
     bit-exact with the single-device one. ``clause_mask`` (n,) freezes
     padding rows of a ragged slice (see ``_class_round``).
-
-    ``stale_votes`` (m,) switches both rounds to asynchronous stale-vote
-    feedback (DESIGN.md §11): no vote psum — each round reads its class's
-    stale remote term instead — and the update returns
-    ``(state, (votes, counts))`` where ``votes``/``counts`` (m,) int32
-    scatter the rounds' *local* partial vote sums by class (the
-    ``VoteAccumulator`` write-buffer contribution). ``axis_name`` is
-    ignored for the vote in this mode.
     """
     lit = literals_from_input(x)
     ta = state.ta_state
@@ -319,19 +292,6 @@ def update_sample(
         n_rows = None if clause_start is None else ta.shape[1]
         rands_a = draw_feedback_rands(cfg, k_a, clause_start, n_rows)
         rands_b = draw_feedback_rands(cfg, k_b, clause_start, n_rows)
-    if stale_votes is not None:
-        row_pos, v_pos = _class_round(
-            cfg, ta[y], lit, rands_a, jnp.asarray(True), pol=pol,
-            clause_mask=clause_mask, stale_vote=stale_votes[y])
-        ta = ta.at[y].set(row_pos)
-        row_neg, v_neg = _class_round(
-            cfg, ta[neg], lit, rands_b, jnp.asarray(False), pol=pol,
-            clause_mask=clause_mask, stale_vote=stale_votes[neg])
-        ta = ta.at[neg].set(row_neg)
-        m = stale_votes.shape[0]
-        votes = jnp.zeros((m,), jnp.int32).at[y].set(v_pos).at[neg].set(v_neg)
-        counts = jnp.zeros((m,), jnp.int32).at[y].set(1).at[neg].set(1)
-        return TMState(ta_state=ta), (votes, counts)
     row_pos = _class_round(cfg, ta[y], lit, rands_a, jnp.asarray(True),
                            pol=pol, axis_name=axis_name,
                            clause_mask=clause_mask)
@@ -351,7 +311,6 @@ def update_batch_sequential(
     clause_start: jax.Array | None = None,
     mask: jax.Array | None = None,
     clause_mask: jax.Array | None = None,
-    stale_votes: jax.Array | None = None,
 ) -> TMState:
     """Faithful online learning over a batch: lax.scan of per-sample updates.
 
@@ -364,32 +323,9 @@ def update_batch_sequential(
     state update — the padding contract for fixed-shape trailing batches.
     ``clause_mask`` (n,) bool marks valid *clause rows*: the transpose
     contract for ragged shard slices (padding rows frozen, DESIGN.md §9).
-
-    ``stale_votes`` (m,) switches every round to asynchronous stale-vote
-    feedback (zero collectives in the scan, DESIGN.md §11) and the return
-    value to ``(state, (votes_sum, counts))`` — the per-class sum and count
-    of local partial votes observed over the batch's rounds (masked rows
-    excluded), from which the caller derives the accumulator's new write
-    buffer. The stale term is constant across the batch: it refreshes at
-    the K-step boundary, never mid-scan.
     """
     keys = jax.random.split(rng, xs.shape[0])
     valid = jnp.ones(xs.shape[0], bool) if mask is None else mask
-
-    if stale_votes is not None:
-        def body_async(carry, inp):
-            st, vs, vc = carry
-            x, y, k, m = inp
-            new, (dv, dc) = update_sample(
-                cfg, st, x, y, k, pol=pol, clause_start=clause_start,
-                clause_mask=clause_mask, stale_votes=stale_votes)
-            st = TMState(ta_state=jnp.where(m, new.ta_state, st.ta_state))
-            return (st, vs + jnp.where(m, dv, 0), vc + jnp.where(m, dc, 0)), None
-
-        zeros = jnp.zeros(stale_votes.shape, jnp.int32)
-        (out, vs, vc), _ = jax.lax.scan(
-            body_async, (state, zeros, zeros), (xs, ys, keys, valid))
-        return out, (vs, vc)
 
     def body(st, inp):
         x, y, k, m = inp
@@ -413,7 +349,6 @@ def update_batch_parallel(
     batch_total: int | None = None,
     mask: jax.Array | None = None,
     clause_mask: jax.Array | None = None,
-    stale_votes: jax.Array | None = None,
 ) -> TMState:
     """Beyond-paper: batch-parallel update (deltas computed vs the *same*
     pre-batch state, then summed). An approximation of online learning —
@@ -427,14 +362,6 @@ def update_batch_parallel(
     the deltas of padded samples (randomness still consumed per row);
     ``clause_mask`` (n,) bool zeroes the deltas of padded clause rows
     (ragged shard slices, DESIGN.md §9).
-
-    ``stale_votes`` (m,) switches the per-sample rounds to asynchronous
-    stale-vote feedback (no per-round vote psum, DESIGN.md §11) and the
-    return value to ``(state, (votes_sum, counts))`` — local partial-vote
-    statistics summed over this rank's valid samples, *not* reduced over
-    ``batch_axes`` (each vote rank keeps its own accumulator row). The
-    delta psum over ``batch_axes`` is unchanged: state composition stays
-    exact; only the vote feedback term is stale.
     """
     if batch_total is None:
         keys = jax.random.split(rng, xs.shape[0])
@@ -443,28 +370,6 @@ def update_batch_parallel(
         kd = jax.random.key_data(jax.random.split(rng, batch_total))
         kd = jax.lax.dynamic_slice_in_dim(kd, batch_start, xs.shape[0], 0)
         keys = jax.random.wrap_key_data(kd)
-
-    if stale_votes is not None:
-        def one_async(x, y, k):
-            new, (dv, dc) = update_sample(
-                cfg, state, x, y, k, pol=pol, clause_start=clause_start,
-                clause_mask=clause_mask, stale_votes=stale_votes)
-            delta = (new.ta_state.astype(jnp.int32)
-                     - state.ta_state.astype(jnp.int32))
-            return delta, dv, dc
-
-        deltas, dvs, dcs = jax.vmap(one_async)(xs, ys, keys)
-        if mask is not None:
-            deltas = jnp.where(mask[:, None, None, None], deltas, 0)
-            dvs = jnp.where(mask[:, None], dvs, 0)
-            dcs = jnp.where(mask[:, None], dcs, 0)
-        deltas = deltas.sum(axis=0)
-        if batch_axes:
-            deltas = jax.lax.psum(deltas, batch_axes)
-        ta = jnp.clip(
-            state.ta_state.astype(jnp.int32) + deltas, 1, 2 * cfg.n_states
-        ).astype(cfg.state_dtype)
-        return TMState(ta_state=ta), (dvs.sum(axis=0), dcs.sum(axis=0))
 
     def one(x, y, k):
         new = update_sample(cfg, state, x, y, k, pol=pol, axis_name=axis_name,

@@ -107,43 +107,6 @@ class TMState(NamedTuple):
         return self.ta_state.shape[2]
 
 
-class VoteAccumulator(NamedTuple):
-    """Double-buffered per-class vote sums for asynchronous sharded training.
-
-    The Massively Parallel TM architecture (PAPERS.md, arXiv 2009.04861)
-    shows clause blocks can apply Type I/II feedback against a slightly
-    *stale* global vote sum instead of synchronising per evaluation. This
-    pytree carries that staleness state in the ``TMBundle`` when a topology
-    trains with ``async_votes=K`` (DESIGN.md §11):
-
-      * ``local``    — (R, m) int32: each vote rank's latest *local* partial
-                       vote sum per class (batch mean of the rounds it ran
-                       since the last refresh; rows untouched in a window
-                       keep their previous value). R is the number of vote
-                       ranks — every (data × clause) mesh position.
-      * ``stale``    — (R, m) int32: the read buffer — each rank's stale
-                       estimate of the *remote* partial-vote sum per class
-                       (the refresh-time global sum minus the rank's own
-                       ``local`` row). The training round reads
-                       ``live local + stale`` instead of psumming.
-      * ``overflow`` — (R,) int32: cache-sync events dropped on this rank
-                       since the last refresh; drained into the bundle's
-                       global ``event_overflow`` by the refresh collective
-                       (never by a per-step psum).
-
-    The two (R, m) buffers are the double buffer: ``local`` accumulates
-    while ``stale`` is read; one batched all-reduce every K steps
-    (``distributed.make_vote_refresh``) swaps fresh sums into ``stale``.
-    The accumulator is *rebuildable* state — checkpoints never persist it
-    (a restore starts from zeros, a cold-start transient that decays within
-    one refresh window), so async checkpoints stay topology-free.
-    """
-
-    local: jax.Array     # (R, m) int32 — latest local partial votes
-    stale: jax.Array     # (R, m) int32 — stale remote vote sums (read buffer)
-    overflow: jax.Array  # (R,)  int32 — per-rank dropped events since refresh
-
-
 def init_tm(cfg: TMConfig, rng: jax.Array | None = None) -> TMState:
     """All TAs start just on the *exclude* side of the boundary (state N).
 

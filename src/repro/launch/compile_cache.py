@@ -1,9 +1,8 @@
 """Persistent XLA compilation cache for the repo's entry points.
 
 Compiling the TM programs for a TPU takes longer than running them a few
-times, so entry points (``chip_smoke.py``, ``launch/tm_serve.py``,
-``benchmarks/tm_speedup.py``) turn on JAX's persistent compilation cache
-before their first compile. Library code never calls this: importing
+times, so entry points (``chip_smoke.py``, ``launch/tm_serve.py``)
+turn on JAX's persistent compilation cache before their first compile. Library code never calls this: importing
 ``repro`` changes no JAX configuration.
 """
 from __future__ import annotations

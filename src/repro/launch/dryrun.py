@@ -270,108 +270,6 @@ def run_tm_checks(*, data: int = 2, model: int = 4, n_clauses: int = 256,
     return record
 
 
-def run_tm_async_checks(*, k: int = 4, n_clauses: int = 256,
-                        train_batch: int = 8, save: bool = True) -> dict:
-    """Lower the async (stale-vote) train path; assert its collective HLO.
-
-    The asynchronous contract (DESIGN.md §11): with ``async_votes=K`` the
-    step executable contains **zero vote collectives** — the per-class-round
-    psum and the per-step overflow psum are both gone — leaving only what
-    state exactness requires (nothing on a clause-only mesh; the reassembly
-    all-reduce under hierarchical composition; the delta all-reduce in
-    batch-parallel mode). The K-step refresh is its own executable with
-    **exactly one** all-reduce (votes + overflow packed together). Per mesh
-    × mode the invariant pins the arithmetic: ``async static collective
-    count == sync count − 3`` — the sync step carries two vote psums (one
-    per class round: the target-class and the sampled-negative round) plus
-    the per-step overflow psum, and async removes all three.
-    """
-    import jax.numpy as jnp
-
-    from repro.core import TMConfig
-    from repro.core.distributed import (
-        make_sharded_prepare, make_sharded_train_step, make_vote_refresh)
-    from repro.core.types import init_tm
-    from repro.launch.mesh import make_host_mesh
-
-    cfg = TMConfig(n_classes=10, n_clauses=n_clauses, n_features=196)
-    record: dict = {"k": k, "n_clauses": n_clauses, "cells": {},
-                    "failures": []}
-    txs = jnp.zeros((train_batch, cfg.n_features), jnp.uint8)
-    tys = jnp.zeros((train_batch,), jnp.int32)
-    tmask = jnp.ones((train_batch,), bool)
-    kd = jax.random.key_data(jax.random.key(0))
-
-    # (mesh, mode) cells × the in-step collective count async may keep:
-    # clause-only sequential has nothing left; composition keeps its
-    # reassembly all-reduce; batch-parallel keeps its delta all-reduce.
-    cells = [("1x4", dict(data=1, model=4), False, 0),
-             ("2x4", dict(data=2, model=4), False, 1),
-             ("2x4", dict(data=2, model=4), True, 1)]
-    for mesh_name, mesh_kw, parallel, allowed in cells:
-        mesh = make_host_mesh(**mesh_kw)
-        bundle = make_sharded_prepare(cfg, mesh, async_votes=k)(init_tm(cfg))
-        mode = "parallel" if parallel else "sequential"
-        key = f"{mesh_name}/{mode}"
-
-        counts = {}
-        for tag, async_votes in (("sync", 0), ("async", k)):
-            step = make_sharded_train_step(
-                cfg, mesh, parallel=parallel, max_events=1024,
-                async_votes=async_votes)
-            args = ((bundle.state, bundle.caches, step.pol, bundle.vote_acc,
-                     txs, tys, kd, tmask) if async_votes else
-                    (bundle.state, bundle.caches, step.pol, txs, tys, kd,
-                     tmask, jnp.zeros((), jnp.int32)))
-            coll = hlo_mod.collective_stats(
-                step.jitted.lower(*args).compile().as_text())
-            counts[tag] = coll
-        refresh = make_vote_refresh(cfg, mesh, parallel=parallel)
-        rcoll = hlo_mod.collective_stats(
-            refresh.jitted.lower(bundle.vote_acc,
-                                 jnp.zeros((), jnp.int32)).compile().as_text())
-
-        a, s = counts["async"], counts["sync"]
-        ok_step = (a.count == allowed and set(a.by_kind) <= {"all-reduce"})
-        ok_delta = a.count == s.count - 3
-        ok_refresh = (rcoll.count == 1
-                      and set(rcoll.by_kind) == {"all-reduce"})
-        record["cells"][key] = {
-            "composition": step.composition,
-            "sync_collectives": s.by_kind, "sync_count": s.count,
-            "async_collectives": a.by_kind, "async_count": a.count,
-            "async_allowed": allowed,
-            "refresh_collectives": rcoll.by_kind,
-            "refresh_count": rcoll.count,
-            "zero_vote_collectives": ok_step,
-            "removed_vote_collectives": ok_delta,
-            "one_refresh_all_reduce": ok_refresh}
-        print(f"[tm-async] {key} ({step.composition}): "
-              f"sync={s.count} async={a.count} (allowed {allowed}) "
-              f"refresh={rcoll.count} "
-              f"{'OK' if ok_step and ok_delta and ok_refresh else 'FAIL'}",
-              flush=True)
-        if not ok_step:
-            record["failures"].append(
-                f"{key}: async step must keep <= {allowed} all-reduce(s), "
-                f"got {a.by_kind} (count={a.count})")
-        if not ok_delta:
-            record["failures"].append(
-                f"{key}: async must remove exactly the two per-round vote "
-                f"psums + the overflow psum (sync {s.count} -> async "
-                f"{a.count}, expected {s.count - 3})")
-        if not ok_refresh:
-            record["failures"].append(
-                f"{key}: refresh must be exactly one batched all-reduce, "
-                f"got {rcoll.by_kind} (count={rcoll.count})")
-
-    if save:
-        out = RESULTS / "tm"
-        out.mkdir(parents=True, exist_ok=True)
-        (out / "async.json").write_text(json.dumps(record, indent=2))
-    return record
-
-
 def main():
     ap = argparse.ArgumentParser(description="multi-pod dry-run")
     ap.add_argument("--arch", default=None)
@@ -382,10 +280,6 @@ def main():
     ap.add_argument("--tm", action="store_true",
                     help="clause-sharded TM lowering checks (every engine; "
                          "asserts the single vote all-reduce)")
-    ap.add_argument("--async-votes", action="store_true",
-                    help="with --tm: also check the async stale-vote train "
-                         "path (zero in-step vote collectives, one "
-                         "all-reduce per K-step refresh)")
     args = ap.parse_args()
 
     if args.tm:
@@ -398,8 +292,6 @@ def main():
             run_tm_checks(data=2, model=3, n_clauses=128,
                           expect_composition="composed_ragged"),
         ]
-        if args.async_votes:
-            records.append(run_tm_async_checks())
         failures = [f for r in records for f in r["failures"]]
         if failures:
             print(f"\n{len(failures)} TM FAILURES:")
@@ -411,8 +303,7 @@ def main():
               "composition rules: "
               + ", ".join(f"{r['mesh']}→"
                           f"{r['train_step_sequential']['composition']}"
-                          for r in records if "train_step_sequential" in r)
-              + ("; async stale-vote route OK" if args.async_votes else "")
+                          for r in records)
               + ")")
         return
 

@@ -15,7 +15,7 @@ generator sweeps offered rates (``serving/loadgen.py``). This module only
 builds sessions, drives the benchmark, and writes the record.
 
 ``BENCH_tm_serve.json`` (schema 2, docs/BENCH_SCHEMAS.md; gitignored
-scratch like ``BENCH_tm.json``) contains:
+scratch) contains:
 
   * ``engines`` — the legacy closed-loop records from ``serve_engine``:
     a simulated arrival clock advanced by *measured* compute times

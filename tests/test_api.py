@@ -255,8 +255,7 @@ def test_topology_validates_and_describes():
     assert t.engines == ("indexed",)  # normalised to a tuple
     assert t.n_devices == 4 and t.is_sharded
     assert Topology().describe() == {
-        "clause_shards": 1, "data_shards": 1, "devices": 1,
-        "async_votes": 0}
+        "clause_shards": 1, "data_shards": 1, "devices": 1}
     with pytest.raises(ValueError, match="must be >= 1"):
         Topology(clause_shards=0)
     with pytest.raises(RuntimeError, match="devices"):

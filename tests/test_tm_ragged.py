@@ -1,7 +1,7 @@
 """Ragged data×clause sharding (DESIGN.md §9): any topology composes.
 
-Fast tests pin the pure resolution table (``distributed.clause_geometry``)
-and the ceil-based per-shard index capacity. The slow subprocess is the
+Fast tests pin the pure resolution table (``distributed.clause_geometry``),
+the per-shard row census and the ceil-based per-shard index capacity. The slow subprocess is the
 acceptance property on a forced **4-device** host platform: a previously
 indivisible topology (``data_shards=2 × clause_shards=2`` on ``n_clauses``
 whose per-shard slice does not divide by the data ranks) trains via
@@ -59,6 +59,21 @@ def test_clause_geometry_table(n_clauses, c, d, n_local, n_padded, n_sub,
         assert d * g.n_sub >= g.n_local
         assert (d - 1) * g.n_sub < g.n_sub_padded
     assert g.n_sub_padded >= g.n_local
+
+
+def test_shard_rows_census():
+    # even: no padding anywhere
+    g = clause_geometry(16, 4, 1)
+    assert g.shard_rows() == [
+        {"shard": i, "real_rows": 4, "pad_rows": 0} for i in range(4)]
+    # ragged: padding lands entirely on the trailing shard(s)
+    g = clause_geometry(10, 4, 1)  # n_local=3 -> rows 3,3,3,1(+2 pad)
+    assert g.shard_rows() == [
+        {"shard": 0, "real_rows": 3, "pad_rows": 0},
+        {"shard": 1, "real_rows": 3, "pad_rows": 0},
+        {"shard": 2, "real_rows": 3, "pad_rows": 0},
+        {"shard": 3, "real_rows": 1, "pad_rows": 2}]
+    assert sum(r["real_rows"] for r in g.shard_rows()) == 10
 
 
 def test_shard_capacity_is_ceil():

@@ -5,12 +5,14 @@ A device profile attributes an op's time to a phase by the scope in the
 op's ``op_name``, so a refactor that drops a scope fails here instead of
 quietly leaving a phase unnamed. Each step is lowered and compiled for a
 tiny config, and each scope must appear in the ``op_name`` metadata of the
-compiled HLO: the single-device step in both learning
-modes, and both shard-local bodies (sync and stale-vote) of the sharded
-step in both modes, on a forced 4-device host mesh (data=2 × model=2, so
-the sequential step composes data × clause). Every all-reduce that the
-per-round vote psum lowers to carries ``tm.votes``; the stale-vote and
-single-device bodies have no vote psum and carry no ``tm.votes``. A step
+compiled HLO: the single-device step in both learning modes, and the
+sharded step on a forced 4-device host mesh in four layouts: both modes
+on data=2 × model=2 (the sequential step composes data × clause), the
+clause-only 1 × 4 bitpack step the four-chip benchmark runs, and a clause
+count the 2 × 2 mesh does not divide (ragged composition). Every
+all-reduce that the per-round vote psum lowers to carries ``tm.votes``,
+every collective of a sharded step is an all-reduce, and the
+single-device step has no vote psum and carries no ``tm.votes``. A step
 whose only cache is the packed words, single-device or clause-sharded,
 compiles no sort or scatter under ``tm.events`` or ``tm.cache_sync``: the
 words are repacked, so the event buffer is dead code.
@@ -116,7 +118,7 @@ def all_reduces(hlo: str) -> list[str]:
 
 
 SHARDED = textwrap.dedent("""
-    import json, os, re
+    import dataclasses, json, os, re
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp
     from repro.core import TMConfig, init_tm
@@ -128,37 +130,36 @@ SHARDED = textwrap.dedent("""
                    s=3.0, threshold=4)
     engines = ("bitpack", "indexed")
     mesh = make_host_mesh(data=2, model=2)
-    state = init_tm(cfg, jax.random.key(0))
     xs = jnp.zeros((4, 6), jnp.uint8)
     ys = jnp.zeros((4,), jnp.int32)
     key = jax.random.key_data(jax.random.key(1))
     mask = jnp.ones((4,), bool)
     out = {}
-    for parallel in (False, True):
-        for k in (0, 2):
-            step = make_sharded_train_step(
-                cfg, mesh, engines=engines, parallel=parallel,
-                max_events=32, async_votes=k)
-            b = make_sharded_prepare(cfg, mesh, engines=engines,
-                                     async_votes=k)(state)
-            if k:
-                args = (b.state, b.caches, step.pol, b.vote_acc, xs, ys,
-                        key, mask)
-            else:
-                args = (b.state, b.caches, step.pol, xs, ys, key, mask,
-                        jnp.zeros((), jnp.int32))
-            hlo = step.jitted.lower(*args).compile().as_text()
-            name = (("parallel" if parallel else "sequential")
-                    + ("_async" if k else "_sync"))
-            out[name] = sorted(set(re.findall(r'op_name="([^"]*)"', hlo)))
-            out[name + "_hlo"] = hlo
-    # the clause-sharded bitpack-only step, as the four-chip benchmark runs
-    step = make_sharded_train_step(cfg, mesh, engines=("bitpack",),
-                                   max_events=32)
-    b = make_sharded_prepare(cfg, mesh, engines=("bitpack",))(state)
-    out["sequential_sync_bitpack_hlo"] = step.jitted.lower(
-        b.state, b.caches, step.pol, xs, ys, key, mask,
-        jnp.zeros((), jnp.int32)).compile().as_text()
+
+    def compile_step(name, cfg, mesh, engines, parallel=False,
+                     composition=None):
+        step = make_sharded_train_step(cfg, mesh, engines=engines,
+                                       parallel=parallel, max_events=32)
+        if composition is not None:
+            assert step.composition == composition, step.composition
+        b = make_sharded_prepare(cfg, mesh, engines=engines)(
+            init_tm(cfg, jax.random.key(0)))
+        hlo = step.jitted.lower(
+            b.state, b.caches, step.pol, xs, ys, key, mask,
+            jnp.zeros((), jnp.int32)).compile().as_text()
+        out[name] = sorted(set(re.findall(r'op_name="([^"]*)"', hlo)))
+        out[name + "_hlo"] = hlo
+
+    compile_step("sequential_sync", cfg, mesh, engines)
+    compile_step("parallel_sync", cfg, mesh, engines, parallel=True)
+    # the clause-sharded bitpack-only step on the 2 x 2 mesh
+    compile_step("sequential_sync_bitpack", cfg, mesh, ("bitpack",))
+    # the four-chip benchmark's layout: clause-only, bitpack only
+    compile_step("clause4_sync", cfg, make_host_mesh(data=1, model=4),
+                 ("bitpack",), composition="clause_only")
+    # 6 clauses: 3 a clause shard, which 2 data ranks cannot split evenly
+    compile_step("ragged_sync", dataclasses.replace(cfg, n_clauses=6), mesh,
+                 engines, composition="composed_ragged")
     print("OPNAMES " + json.dumps(out))
 """)
 
@@ -175,29 +176,42 @@ def sharded_op_names():
     return json.loads(line[len("OPNAMES "):])
 
 
-@pytest.mark.parametrize("body", ["sequential_sync", "sequential_async",
-                                  "parallel_sync", "parallel_async"])
+SHARDED_BODIES = ["sequential_sync", "parallel_sync", "clause4_sync",
+                  "ragged_sync"]
+
+
+@pytest.mark.parametrize("body", SHARDED_BODIES)
 def test_sharded_step_bodies_carry_every_scope(sharded_op_names, body):
     assert scopes_in(sharded_op_names[body]) == list(SCOPES)
 
 
-@pytest.mark.parametrize("body", ["sequential_sync", "parallel_sync"])
-def test_vote_psum_all_reduces_carry_the_votes_scope(sharded_op_names, body):
+@pytest.mark.parametrize("body,n_others", [
+    ("sequential_sync", 2), ("parallel_sync", 2), ("clause4_sync", 1),
+    ("ragged_sync", 2)],
+    ids=["sequential_sync", "parallel_sync", "clause4_sync", "ragged_sync"])
+def test_vote_psum_all_reduces_carry_the_votes_scope(sharded_op_names, body,
+                                                     n_others):
     ops = all_reduces(sharded_op_names[body + "_hlo"])
     # the rounds' all-reduces: inside the scan's body, or under the vmap
     in_rounds = [n for n in ops if "/while/body/" in n or "vmap(" in n]
     assert len(in_rounds) == 2, ops          # one per class round
     assert all(re.search(rf"(^|/)(\w+\()*{re.escape(scopes.VOTES)}\)*/", n)
                for n in in_rounds), in_rounds
-    # the step's other all-reduces (overflow count, state reassembly or
-    # delta sum) are not votes
+    # the step's other all-reduces are not votes: the overflow count, and
+    # where the mesh has data ranks the state reassembly or delta sum
     others = [n for n in ops if n not in in_rounds]
-    assert len(others) == 2 and not any(scopes.VOTES in n for n in others)
+    assert len(others) == n_others, ops
+    assert not any(scopes.VOTES in n for n in others)
 
 
-@pytest.mark.parametrize("body", ["sequential_async", "parallel_async"])
-def test_stale_vote_bodies_carry_no_votes_scope(sharded_op_names, body):
-    assert not any(scopes.VOTES in n for n in sharded_op_names[body])
+@pytest.mark.parametrize("body", SHARDED_BODIES)
+def test_sharded_train_step_collectives_are_all_reduces(sharded_op_names,
+                                                        body):
+    hlo = sharded_op_names[body + "_hlo"]
+    for kind in ("all-gather", "all-to-all", "collective-permute",
+                 "reduce-scatter"):
+        assert not re.search(rf"\b{kind}(-start)?\(", hlo), kind
+    assert all_reduces(hlo)
 
 
 @pytest.mark.parametrize("body,selects", [

@@ -62,7 +62,7 @@ SCRIPT = textwrap.dedent("""
     stm = TMSession(cfg, mesh=mesh, max_events=ALL)
     assert stm.describe() == {"clause_shards": 4, "data_shards": 2,
                               "devices": 8, "sharded": True,
-                              "backend": "xla", "async_votes": 0,
+                              "backend": "xla",
                               "composition": "composed_even",
                               "shard_rows": [
                                   {"shard": i, "real_rows": 4, "pad_rows": 0}
