@@ -15,6 +15,11 @@ data made from a seed:
   (``repro.core.scores``), and the AOT cache may not miss.
 * route: the resolved backend is ``pallas`` and every lowered train and
   scores step holds a ``tpu_custom_call`` (and the XLA route none).
+* rows: the learning round's ``clause_outputs_rows`` kernel against its
+  XLA body on seeded TA rows at the M1, I1 and I4-shard widths (2000 ×
+  1568, 2000 × 10,000, 5000 × 40,000), whose trailing literal tile is
+  partial at I1 and I4: lanes the kernel failed to mask would read
+  whatever the chip's memory holds there, which interpret mode cannot show.
 
 ``--chips 4`` runs only the sharded phase instead: a few steps and scores
 on ``Topology(clause_shards=4)`` and ``Topology(data_shards=2,
@@ -48,6 +53,8 @@ N_REQUESTS = 48      # served rows (one chip); a multiple of every data axis
 N_SHARDED_ROWS = 16  # scored rows per topology (four chips)
 MAX_BATCH = 8        # top AOT padding bucket
 ENGINES = ("indexed", "bitpack")
+ROW_WIDTHS = {"M1": (2000, 1568), "I1": (2000, 10000),
+              "I4 shard": (5000, 40000)}   # (clauses, literals)
 
 
 def check(cond: bool, msg: str) -> None:
@@ -134,6 +141,40 @@ def serve(cfg, state, requests, engine: str):
     text = session.lower_scores(bundle, MAX_BATCH,
                                 engine=engine).lowered.as_text()
     return np.stack([r.scores for r in results]), text, compile_s
+
+
+def rows_parity(n_states: int, widths=ROW_WIDTHS, mode="pallas") -> None:
+    """``clause_outputs_rows`` on the ``mode`` route against its XLA body,
+    bit for bit, on seeded rows at each of ``widths``. Each clause includes
+    about two literals, so outputs mix 0 and 1; row 0 includes only the
+    last literal, which is false (output 0), and row 1 includes nothing
+    (output 1)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.kernels import backend as kbackend
+    kernel = kbackend.resolve("clause_outputs_rows", mode)
+    body = kbackend.resolve("clause_outputs_rows", "xla")
+    key = jax.random.key(SEED)
+    for name, (n, L) in widths.items():
+        k_ta, k_inc, k_lit, key = jax.random.split(key, 4)
+        ta = jax.random.randint(k_ta, (n, L), 1, n_states + 1, jnp.int16)
+        inc = jax.random.uniform(k_inc, (n, L)) < 2.0 / L
+        inc = inc.at[0].set(jnp.arange(L) == L - 1).at[1].set(False)
+        ta = jnp.where(inc, ta + n_states, ta).astype(jnp.int16)
+        lit = jax.random.bernoulli(k_lit, 0.5, (L,)).astype(jnp.uint8)
+        lit = lit.at[L - 1].set(0)
+        got = np.asarray(kernel(ta, lit, n_states=n_states))
+        want = np.asarray(body(ta, lit, n_states=n_states))
+        check(np.array_equal(got, want),
+              f"clause_outputs_rows at {name} ({n} x {L}): "
+              f"{int((got != want).sum())} clauses differ from the XLA body")
+        check(list(want[:2]) == [0, 1] and 0 < want.mean() < 1,
+              f"clause_outputs_rows at {name}: rows 0 and 1 read "
+              f"{list(want[:2])}, mean {want.mean():.3f}")
+        log(f"[rows] clause_outputs_rows at {name} ({n} x {L}): equal to "
+            f"the XLA body; {int(want.sum())} of {n} clauses true")
 
 
 def train_and_serve(cfg, backends=("auto", "xla")) -> dict:
@@ -258,6 +299,7 @@ def main(argv=None) -> int:
     if args.chips == 4:
         sharded(cfg)
     else:
+        rows_parity(cfg.n_states)
         out = train_and_serve(cfg)
         check(out["auto"]["resolved"] == "pallas",
               f"backend 'auto' resolved to {out['auto']['resolved']!r}")

@@ -166,33 +166,6 @@ def uniform_rows(key: jax.Array, start: jax.Array, n_rows: int,
     return jnp.maximum(0.0, floats)
 
 
-def _round_clause_outputs(cfg: TMConfig, ta_row: jax.Array,
-                          lit: jax.Array, mode: str) -> jax.Array:
-    """(n,) uint8 clause outputs of one class row (learning semantics:
-    empty clauses → 1), through the backend-resolved evaluation body.
-
-    ``mode`` is a *concrete* backend (``kernels/backend.resolve_backend``).
-    The XLA body is the dense float-einsum falsification count; the Pallas
-    body packs the row's include mask on the fly (a cheap VPU reshape-sum)
-    and runs the bit-packed clause-output kernel — the first stage of the
-    fused training round, so the (n, 2o) include mask never feeds a dense
-    einsum and the clause outputs stream straight into the ``ta_update``
-    kernel. Both bodies are bit-exact (same falsification predicate).
-    """
-    include = ta_row > cfg.n_states
-    if mode == "xla":
-        false_cnt = jnp.einsum(
-            "k,nk->n", (1 - lit).astype(jnp.float32),
-            include.astype(jnp.float32))
-        return (false_cnt < 0.5).astype(jnp.uint8)
-    from repro.core.bitpack import pack_bits
-    from repro.kernels import backend as kbackend
-    outputs = kbackend.resolve("clause_outputs", mode)
-    inc_packed = pack_bits(include.astype(jnp.uint8))[None]   # (1, n, W)
-    lit_packed = pack_bits(lit.astype(jnp.uint8)[None])       # (1, W)
-    return outputs(inc_packed, lit_packed)[0, 0].astype(jnp.uint8)
-
-
 def _class_round(
     cfg: TMConfig,
     ta_row: jax.Array,       # (n, 2o) — states of one class (or a clause shard)
@@ -223,16 +196,20 @@ def _class_round(
     so the mask never touches the vote sum.
 
     Both halves of the round resolve through the kernel backend registry
-    (``cfg.backend``): clause evaluation (``clause_outputs``) and feedback
-    application (``ta_update``). On the Pallas backends this is the fused
-    training round — packed-word clause outputs piped into the ``ta_update``
-    kernel with only the scalar vote in between, bit-exact with the XLA
-    bodies (tests/test_kernel_backends.py pins it in both learning modes).
+    (``cfg.backend``): clause evaluation (``clause_outputs_rows``, (n,) int8,
+    empty clauses → 1) and feedback application (``ta_update``). The XLA
+    body of the evaluation is a dense float-einsum falsification count. On
+    the Pallas backends this is the fused training round: the clause
+    outputs are read straight from the int16 row, nothing packed, and piped
+    into the ``ta_update`` kernel with only the scalar vote in between,
+    bit-exact with the XLA bodies (tests/test_kernel_backends.py pins it in
+    both learning modes).
     """
     from repro.kernels import backend as kbackend
 
     mode = kbackend.resolve_backend(cfg.backend)
-    clause_out = _round_clause_outputs(cfg, ta_row, lit, mode)
+    clause_out = kbackend.resolve("clause_outputs_rows", mode)(
+        ta_row, lit, n_states=cfg.n_states)
     if pol is None:
         pol = clause_polarity(cfg)
     t = float(cfg.threshold)

@@ -35,7 +35,11 @@ contract cannot drift from the code), and ``launch/dryrun.py --tm``
 asserts the lowered collective profile per backend.
 
 Primitives registered at import: ``clause_votes``, ``clause_outputs``,
-``ta_update``, ``indexed_votes``, ``index_update``.
+``clause_outputs_rows``, ``ta_update``, ``indexed_votes``,
+``index_update``. ``clause_outputs`` reads packed include words (the
+callers that hold them: ``kernels/ops.py``); ``clause_outputs_rows``
+reads one class's int16 TA row (the learning round, which holds no
+words). The two share only the falsification predicate.
 """
 from __future__ import annotations
 
@@ -190,6 +194,18 @@ def _clause_outputs_xla(include_packed: jax.Array,
     return (~jnp.any(viol != 0, axis=-1)).astype(jnp.int8)
 
 
+def _clause_outputs_rows_xla(ta_row: jax.Array, lit: jax.Array, *,
+                             n_states: int) -> jax.Array:
+    """(n, 2o) TA row + (2o,) literals → (n,) int8 clause outputs
+    (learning semantics: empty clauses → 1), as a dense float-einsum
+    count of the included literals that are false."""
+    include = ta_row > n_states
+    false_cnt = jnp.einsum(
+        "k,nk->n", (1 - lit).astype(jnp.float32),
+        include.astype(jnp.float32))
+    return (false_cnt < 0.5).astype(jnp.int8)
+
+
 def _ta_update_xla(
     ta_row: jax.Array,       # (n, 2o) int16
     lit: jax.Array,          # (2o,)
@@ -250,6 +266,21 @@ register_primitive(Primitive(
         in_specs=(P(None, CLAUSE_AXIS, None),
                   P(None, None)),
         out_spec=P(None, None, CLAUSE_AXIS),    # (B, m, n)
+        vote_reduce=False,
+        clause_padding="caller_sliced",         # outputs feed a 0-pol vote
+    ),
+))
+
+# Clause outputs of one class's TA row (the learning round): the row tiles
+# by clause, the literals replicate, and no collective is needed.
+register_primitive(Primitive(
+    name="clause_outputs_rows",
+    xla=_clause_outputs_rows_xla,
+    pallas=clause_eval.clause_outputs_rows,
+    partitioning=ClausePartitioning(
+        in_specs=(P(CLAUSE_AXIS, None),         # ta_row (n, 2o)
+                  P(None)),                     # lit (2o,)
+        out_spec=P(CLAUSE_AXIS),                # (n,)
         vote_reduce=False,
         clause_padding="caller_sliced",         # outputs feed a 0-pol vote
     ),

@@ -1,4 +1,4 @@
-"""Pallas TPU kernels: bit-packed clause evaluation (+ fused voting).
+"""Pallas TPU kernels: clause evaluation (+ fused voting).
 
 The paper's dense hot spot is evaluating m·n conjunctive clauses over 2o
 literals. TPU-native layout (DESIGN.md §2):
@@ -19,6 +19,10 @@ with 0 (votes) or their outputs fall outside the array (dropped writes).
 VMEM budget per grid step: include block CLAUSE_TILE×W×4B + literal block
 BATCH_TILE×W×4B + the (BATCH_TILE, CLAUSE_TILE, W) violation temporary;
 W = 1250 at 2o = 40k literals → ≈ 6 MB, inside the 16 MB scoped default.
+
+The learning round holds one class's int16 TA row, not packed words, so
+``clause_outputs_rows`` evaluates its clauses straight from the row: one
+read of the row's states, nothing packed on the way.
 """
 from __future__ import annotations
 
@@ -31,6 +35,9 @@ from jax.experimental import pallas as pl
 BATCH_TILE = 8       # sublane-friendly batch tile
 CLAUSE_TILE = 128    # clauses per grid step
 LANE = 128           # lane width; the vote block's class axis pads to it
+ROW_CLAUSE_TILE = 256   # row kernel: clauses per grid step (16-row int16 tiles)
+ROW_LIT_TILE = 2048     # row kernel: literal lanes per grid step; with
+                        # ROW_CLAUSE_TILE, 1 MB of int16 states a step
 
 
 def pad_to(x: jax.Array, axis: int, mult: int, value=0) -> jax.Array:
@@ -176,3 +183,73 @@ def clause_outputs_packed(
         interpret=interpret,
     )(include_packed, lit)
     return jnp.transpose(out[:, :b], (1, 0, 2)).astype(jnp.int8)
+
+
+# ---------------------------------------------------------------------------
+# Clause outputs from one class's TA row (the learning round)
+# ---------------------------------------------------------------------------
+
+
+def _row_outputs_kernel(ta_ref, lit_ref, o_ref, *, n_states: int,
+                        n_lits: int):
+    """Grid (clause tiles, literal tiles); j, the literal tile, is the
+    reduction and iterates fastest.
+
+    ta_ref: (Ct, Lt) int16 — the clause tile's TA states
+    lit_ref: (1, Lt) int32 — literal truth values
+    o_ref:  (Ct, 1) int32  — resident over j: the largest state among the
+            clause's false literals, then, at the last tile, the output
+            (1 iff that state excludes, i.e. no included literal is false)
+    """
+    j = pl.program_id(1)
+    lt = lit_ref.shape[1]
+
+    @pl.when(j == 0)
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    false_lit = lit_ref[...] == 0                          # (1, Lt)
+    if n_lits % lt:
+        # the trailing tile's lanes past n_lits hold unspecified values
+        lane = j * lt + jax.lax.broadcasted_iota(jnp.int32, false_lit.shape, 1)
+        false_lit = false_lit & (lane < n_lits)
+    ta = ta_ref[...].astype(jnp.int32)
+    # states are >= 1, so 0 stands for "no false literal here"
+    worst = jnp.max(jnp.where(false_lit, ta, 0), axis=1, keepdims=True)
+    o_ref[...] = jnp.maximum(o_ref[...], worst)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _emit():
+        o_ref[...] = (o_ref[...] <= n_states).astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("n_states", "interpret"))
+def clause_outputs_rows(
+    ta_row: jax.Array,   # (n, 2o) int16 — one class's TA states
+    lit: jax.Array,      # (2o,) literal truth values
+    *,
+    n_states: int,
+    interpret: bool = True,
+) -> jax.Array:
+    """Clause outputs of one TA row: (n,) int8 (empty clauses → 1).
+
+    A clause is falsified iff some literal it includes (state above
+    ``n_states``) is false. Clauses sit on sublanes and literals on lanes,
+    as in ``ta_update``; each grid step reads ROW_CLAUSE_TILE ×
+    ROW_LIT_TILE states. Trailing tiles may be partial: out-of-range
+    lanes are masked, out-of-range clause rows only reach dropped writes.
+    """
+    n, L = ta_row.shape
+    ct, lt = min(n, ROW_CLAUSE_TILE), min(L, ROW_LIT_TILE)
+    out = pl.pallas_call(
+        functools.partial(_row_outputs_kernel, n_states=n_states, n_lits=L),
+        grid=(pl.cdiv(n, ct), pl.cdiv(L, lt)),
+        in_specs=[
+            pl.BlockSpec((ct, lt), lambda i, j: (i, j)),
+            pl.BlockSpec((1, lt), lambda i, j: (0, j)),
+        ],
+        out_specs=pl.BlockSpec((ct, 1), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.int32),
+        interpret=interpret,
+    )(ta_row.astype(jnp.int16), lit.astype(jnp.int32)[None, :])
+    return out[:, 0].astype(jnp.int8)

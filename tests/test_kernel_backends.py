@@ -108,6 +108,13 @@ def test_partitioning_contract_matches_sharded_wiring():
     assert outputs.out_spec == P(None, None, CLAUSE_AXIS)
     assert not outputs.vote_reduce
 
+    rows = kbackend.get_primitive("clause_outputs_rows").partitioning
+    # the learning round's evaluation reads the same TA row slice that
+    # ta_update rewrites, and its outputs tile with the row's clauses
+    assert rows.in_specs == (P(CLAUSE_AXIS, None), P(None))
+    assert rows.out_spec == P(CLAUSE_AXIS)
+    assert not rows.vote_reduce
+
     upd = kbackend.get_primitive("ta_update").partitioning
     # a TA class row (n, 2o) is one class slice of STATE_PSPEC (m, n, 2o)
     assert STATE_PSPEC.ta_state == P(None, CLAUSE_AXIS, None)
@@ -153,6 +160,11 @@ def _primitive_case(name, seed):
         return (inc_packed, lit_packed, pol), {}
     if name == "clause_outputs":
         return (inc_packed, lit_packed), {}
+    if name == "clause_outputs_rows":
+        from repro.core.types import literals_from_input
+        ta = np.where(include[seed % m], 51, rng.integers(1, 51, (n, 2 * o)))
+        return (jnp.asarray(ta, jnp.int16),
+                literals_from_input(x)[seed % b]), {"n_states": 50}
     if name == "ta_update":
         L = 2 * o
         return (
@@ -204,6 +216,42 @@ def test_primitive_pallas_matches_xla(name, seed):
     assert len(got_leaves) == len(want_leaves)  # e.g. index_update's 3-tuple
     for g, w in zip(got_leaves, want_leaves):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("n,L", [(40, 4200), (300, 4200), (300, 4096),
+                                 (7, 130)])
+def test_clause_outputs_rows_partial_tiles(n, L):
+    """The row kernel at shapes whose trailing literal tile (and, past 256
+    clauses, clause tile) is partial, against the XLA body: empty clauses
+    evaluate to 1, and a clause whose only false included literal sits in
+    the last literal tile evaluates to 0."""
+    rng = np.random.default_rng(n * L)
+    n_states = 127
+    ta = rng.integers(1, n_states + 1, (n, L))              # all excluded
+    lit = rng.integers(0, 2, L).astype(np.uint8)
+    false_k = np.flatnonzero(lit == 0)
+    true_k = np.flatnonzero(lit == 1)
+    # clause 0 stays empty; clause 1 includes only true literals
+    ta[1, true_k[:5]] = n_states + 1
+    # clause 2: its one false included literal is the last false literal
+    ta[2, true_k[:5]] = n_states + 1
+    ta[2, false_k[-1]] = 2 * n_states
+    assert false_k[-1] >= (L - 1) // 2048 * 2048            # last lane tile
+    # the rest: random includes at a density that leaves some clauses true
+    rest = rng.uniform(size=(n - 3, L)) < 2.0 / L
+    ta[3:] = np.where(rest, rng.integers(n_states + 1, 2 * n_states + 1,
+                                         (n - 3, L)), ta[3:])
+    args = (jnp.asarray(ta, jnp.int16), jnp.asarray(lit))
+    want = kbackend.resolve("clause_outputs_rows", "xla")(
+        *args, n_states=n_states)
+    got = kbackend.resolve("clause_outputs_rows", "pallas_interpret")(
+        *args, n_states=n_states)
+    assert got.shape == (n,) and got.dtype == jnp.int8
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert list(np.asarray(got)[:3]) == [1, 1, 0]
+    inc = ta > n_states
+    assert np.array_equal(np.asarray(got),
+                          ~(inc & (lit == 0)[None]).any(axis=1))
 
 
 def test_every_primitive_has_a_case():

@@ -95,6 +95,7 @@ def test_partitioning_declares_clause_padding():
     assert pad["clause_votes"] == "zero_polarity"
     assert pad["ta_update"] == "masked_active"
     assert pad["clause_outputs"] == "caller_sliced"
+    assert pad["clause_outputs_rows"] == "caller_sliced"
 
 
 # ---------------------------------------------------------------------------

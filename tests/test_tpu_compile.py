@@ -6,14 +6,18 @@ kernel is compiled with ``interpret=False`` for one chip of a *described*
 ``v5e:2x2`` topology — nothing runs, so no TPU is needed — at M1 width
 (``configs/tm.py`` ``mnist_like(1)``: 10 classes, 2000 clauses, 1568
 literals) and, for ``indexed_votes``, also at I1 width (2o = 10,000).
-The clause-sharded train step compiles for all four chips of the topology
-at I4 with 20,000 clauses (``imdb_like(20000, 20000)``), where each chip
-draws only its own 5,000 clause rows of the uniforms.
+The learning round's ``clause_outputs_rows`` compiles at M1, I1 and one
+I4 clause shard's width (5,000 clauses, 2o = 40,000), and the one-chip I1
+train step packs nothing inside its rounds. The clause-sharded train step
+compiles for all four chips of the topology at I4 with 20,000 clauses
+(``imdb_like(20000, 20000)``), where each chip draws only its own 5,000
+clause rows of the uniforms.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process at a time may load the TPU compiler library, and every
 test worker imports this file.
 """
+import dataclasses
 import os
 import re
 
@@ -28,6 +32,7 @@ from repro.kernels import clause_eval, indexed, ta_update
 
 M1 = mnist_like(1).tm
 I1 = imdb_like(5000).tm
+I4_SHARD = imdb_like(20000, 5000).tm   # one of four clause shards of I4/20k
 SERVE_BATCH = 32
 
 
@@ -71,6 +76,10 @@ def _kernel_case(name, cfg):
         return (lambda a, b: clause_eval.clause_outputs_packed(
                     a, b, interpret=False),
                 (((1, n, w), jnp.uint32), ((1, w), jnp.uint32)))
+    if name == "clause_outputs_rows":
+        return (lambda a, b: clause_eval.clause_outputs_rows(
+                    a, b, n_states=cfg.n_states, interpret=False),
+                (((n, L), jnp.int16), ((L,), jnp.uint8)))
     if name == "indexed_votes":
         return (lambda a, b, c: indexed.indexed_votes(
                     a, b, c, interpret=False),
@@ -91,9 +100,12 @@ def _kernel_case(name, cfg):
     ("indexed_votes", "M1"),
     ("ta_update", "M1"),
     ("indexed_votes", "I1"),
+    ("clause_outputs_rows", "M1"),
+    ("clause_outputs_rows", "I1"),
+    ("clause_outputs_rows", "I4_SHARD"),
 ])
 def test_kernel_compiles_for_v5e(one_chip, name, width):
-    cfg = {"M1": M1, "I1": I1}[width]
+    cfg = {"M1": M1, "I1": I1, "I4_SHARD": I4_SHARD}[width]
     fn, shapes = _kernel_case(name, cfg)
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
@@ -156,6 +168,40 @@ def test_bitpack_step_selects_no_events_for_v5e(v5e_train_step_hlo):
                        v5e_train_step_hlo)
     assert not [name for name in names
                 if {scopes.EVENTS, scopes.CACHE_SYNC} & set(name.split("/"))]
+
+
+def test_i1_rounds_pack_nothing_for_v5e(one_chip):
+    """The one-chip I1 train step as the benchmark runs it (32 samples,
+    bitpack cache, Pallas rounds) evaluates each round's clauses straight
+    from the int16 TA row: no op inside the scan's ``while`` has the
+    ``u32[..., 10016]`` shape of the row's packed include bits, and both
+    rounds call ``clause_outputs_rows``. The step's own repack of the
+    bitpack words, after the scan, is the only pack left."""
+    cfg = dataclasses.replace(I1, backend="pallas")
+    batch = 32
+
+    def place(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    bundle = jax.tree.map(place, jax.eval_shape(
+        lambda: init_bundle(cfg, engines=("bitpack",),
+                            rng=jax.random.key(0))))
+    xs = place(jax.ShapeDtypeStruct((batch, cfg.n_features), jnp.uint8))
+    ys = place(jax.ShapeDtypeStruct((batch,), jnp.int32))
+    key = place(jax.eval_shape(lambda: jax.random.key(0)))
+    max_events = 2 * cfg.n_clauses * cfg.n_literals
+    hlo = jax.jit(train_step, static_argnames=("parallel", "max_events")
+                  ).lower(bundle, xs, ys, key, None, parallel=False,
+                          max_events=max_events).compile().as_text()
+    width = -(-cfg.n_literals // 32) * 32
+    assert width == 10016
+    packed = [ln for ln in hlo.splitlines()
+              if re.search(rf"= u32\[[0-9,]*\b{width}\]", ln)]
+    assert packed                              # the repack after the scan
+    assert not [ln for ln in packed if "/while/body/" in ln]
+    rounds = re.findall(r"^\s*%(clause_outputs\w*?)(?:\.\d+)? = .*"
+                        r"custom-call\(", hlo, re.M)
+    assert rounds == ["clause_outputs_rows"] * 2
 
 
 def test_clause4_step_draws_only_its_own_rows_for_v5e(v5e, monkeypatch):
